@@ -37,15 +37,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.engine import (
+    META_PRUNE_SHIFT,
     FaultState,
     HyCAConfig,
     RepairPlan,
-    _pe_grids,
     abft_checksums,
     apply_fault_epilogue,
     fault_meta_grid,
     hyca_matmul,
-    repaired_grid,
     validate_fault_state,
     validate_repair_plan,
 )
@@ -345,35 +344,19 @@ class FTContext:
             return resolve_block(m, n, k, dtype=jnp.float32, backend=self.fused_backend)
         return self.fused_block
 
-    def _kernel_grids(self, plan: RepairPlan | None):
-        """Per-PE (bit, val, eff, prune) int32 grids for the kernel drain —
-        the unpacked form of ``engine.fault_meta_grid``, plan-gathered so the
-        RepairPlan costs the kernel nothing (an in-epilogue column view)."""
-        cfg = self.hyca
-        bit, val, faulty = _pe_grids(self.state, cfg.rows, cfg.cols)
-        capacity = cfg.capacity if cfg.mode == "protected" else 0
-        repaired = repaired_grid(self.state, cfg.rows, cfg.cols, capacity)
-        if plan is not None:
-            cm = plan.col_map
-            bit, val, faulty = bit[:, cm], val[:, cm], faulty[:, cm]
-            repaired = repaired[:, cm]
-            prune = plan.prune[:, cm].astype(jnp.int32)
-        else:
-            prune = jnp.zeros((cfg.rows, cfg.cols), jnp.int32)
-        eff = (faulty & ~repaired).astype(jnp.int32)
-        return bit, val, eff, prune
-
-    def _prune_mask(self, plan: RepairPlan | None, prune: jax.Array,
+    def _prune_mask(self, plan: RepairPlan | None, meta: jax.Array,
                     bm: int, bn: int, mp: int, np_: int) -> jax.Array | None:
         """Element-granular prune AND-mask for the kernel drain (the engine
         zeroes pruned PEs per output ELEMENT, and the dispatch layer keeps
-        that placement at any block size).  A single periodic (bm, bn) tile
-        when the block is PE-aligned — broadcast to every grid cell, no
-        per-tile HBM traffic — else the full padded (mp, np_) mask."""
+        that placement at any block size), from the prune bit of the packed
+        meta grid.  A single periodic (bm, bn) tile when the block is
+        PE-aligned — broadcast to every grid cell, no per-tile HBM traffic —
+        else the full padded (mp, np_) mask."""
         if plan is None:
             return None
         cfg = self.hyca
-        keep = jnp.where(prune > 0, jnp.int32(0), jnp.int32(-1))
+        pruned = (meta >> META_PRUNE_SHIFT) & 1
+        keep = jnp.where(pruned > 0, jnp.int32(0), jnp.int32(-1))
         if bm % cfg.rows == 0 and bn % cfg.cols == 0:
             return jnp.tile(keep, (bm // cfg.rows, bn // cfg.cols))
         return jnp.tile(keep, (-(-mp // cfg.rows), -(-np_ // cfg.cols)))[:mp, :np_]
@@ -423,11 +406,10 @@ class FTContext:
         mp, kp, np_ = -(-m // bm) * bm, -(-k // bk) * bk, -(-n // bn) * bn
         xp = jnp.pad(x2.astype(jnp.float32), ((0, mp - m), (0, kp - k)))
         wp = jnp.pad(w.astype(jnp.float32), ((0, kp - k), (0, np_ - n)))
-        bit, val, eff, prune = self._kernel_grids(plan)
+        meta = fault_meta_grid(self.state, cfg, plan)
         out = ft_matmul(
-            xp, wp, bit, val, eff, self._prune_mask(plan, prune, bm, bn, mp, np_),
-            bm=bm, bn=bn, bk=bk, rows=cfg.rows, cols=cfg.cols,
-            interpret=self.fused_backend == "interpret",
+            xp, wp, meta, self._prune_mask(plan, meta, bm, bn, mp, np_),
+            bm=bm, bn=bn, bk=bk, interpret=self.fused_backend == "interpret",
         )
         return out[:m, :n].reshape(*lead, n)
 
@@ -462,13 +444,20 @@ class FTContext:
         mp, kp, np_ = -(-m // bm) * bm, -(-kdim // bk) * bk, -(-n // bn) * bn
         xp = jnp.pad(xe.astype(jnp.float32), ((0, 0), (0, mp - m), (0, kp - kdim)))
         wp = jnp.pad(w.astype(jnp.float32), ((0, 0), (0, kp - kdim), (0, np_ - n)))
-        bit, val, eff, prune = self._kernel_grids(plan)
+        meta = fault_meta_grid(self.state, cfg, plan)
         out = ft_matmul_batched(
-            xp, wp, bit, val, eff, self._prune_mask(plan, prune, bm, bn, mp, np_),
-            bm=bm, bn=bn, bk=bk, rows=cfg.rows, cols=cfg.cols,
-            interpret=self.fused_backend == "interpret",
+            xp, wp, meta, self._prune_mask(plan, meta, bm, bn, mp, np_),
+            bm=bm, bn=bn, bk=bk, interpret=self.fused_backend == "interpret",
         )
         return out[:, :m, :n].reshape(e, b, c, n).transpose(1, 0, 2, 3)
+
+
+def fused_backend() -> str:
+    """The fused dispatch's backend on this process's default device: the
+    compiled Pallas kernel on a TPU, the single-pass jnp formulation
+    elsewhere.  Never a fallback: a kernel that cannot lower on the chip
+    raises there."""
+    return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
 def build_ftcontext(
@@ -508,7 +497,7 @@ def build_ftcontext(
     if plan is not None:
         for p in (plan.values() if isinstance(plan, dict) else (plan,)):
             validate_repair_plan(p, hyca.rows, hyca.cols)
-    backend = "pallas" if jax.default_backend() == "tpu" else "ref"
+    backend = fused_backend()
     from repro.kernels import autotune  # deferred: keeps core import-light
 
     if fused_block == "auto":

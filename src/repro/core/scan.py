@@ -232,11 +232,7 @@ class ScanEngine:
 
         if self.backend == "jnp":
             return probe_check_ref(px, pw, ar, window=self.cfg.window)
-        kdim = px.shape[-1]
-        bk = self.cfg.window if kdim % self.cfg.window == 0 else kdim
-        return probe_check(
-            px, pw, ar, bk=bk, interpret=self.backend == "interpret"
-        ).astype(bool)
+        return probe_check(px, pw, ar, interpret=self.backend == "interpret").astype(bool)
 
     # -- state ------------------------------------------------------------ #
     def init_state(self) -> ScanState:

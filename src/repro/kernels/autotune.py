@@ -168,10 +168,9 @@ def _time_block(m: int, n: int, k: int, dtype, block: tuple[int, int, int],
     mp, np_, kp = _round_up(m, bm), _round_up(n, bn), _round_up(k, bk)
     x = jnp.asarray(rng.standard_normal((mp, kp)), dtype)
     w = jnp.asarray(rng.standard_normal((kp, np_)), dtype)
-    zero = jnp.zeros((rows, cols), jnp.int32)
     run = functools.partial(
-        ft_matmul, x, w, zero, zero, zero,
-        bm=bm, bn=bn, bk=bk, rows=rows, cols=cols, interpret=interpret,
+        ft_matmul, x, w, jnp.zeros((rows, cols), jnp.int32),
+        bm=bm, bn=bn, bk=bk, interpret=interpret,
     )
     jax.block_until_ready(run())  # compile + warmup
     best = float("inf")

@@ -137,7 +137,7 @@ def probe_check(
     pw: jax.Array,
     ar: jax.Array,
     *,
-    bk: int = 8,
+    bk: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Pallas AR == BAR + PR scan probe: one fused pass over the row-block.
@@ -148,9 +148,16 @@ def probe_check(
     list-buffer comparator of Section IV-D.  f32 accumulation is exact for
     the small-int probe operands (|acc| << 2^24).  Returns (block, cols)
     int32 mismatch flags.
+
+    ``bk`` defaults to a K-block the TPU compiler accepts for any K: 128-lane
+    panels when K is a multiple of 128, else the whole K in one panel.
+    Where the PR/BAR boundary falls only regroups the sum, so the flags do
+    not depend on it.
     """
     block, kdim = px.shape
     _, cols = pw.shape
+    if bk is None:
+        bk = 128 if kdim % 128 == 0 else kdim
     assert kdim % bk == 0, (kdim, bk)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,

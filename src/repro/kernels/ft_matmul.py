@@ -15,13 +15,16 @@ fault-injection mux.  One kernel, one HBM write per tile, zero scatter:
     faulty & unrepaired     -> stuck-at applied at drain      (degraded array)
     pruned (RepairPlan)     -> zero at drain                  (plan epilogue)
 
-The kernel consumes *pre-resolved* per-PE metadata: ``pe_eff`` is
-``faulty & ~repaired`` (the only case that leaves the fault in), already
-gathered through the RepairPlan's ``col_map`` by the caller — so a plan's
-remap costs nothing at run time.  The stuck-at mux is applied at the kernel
-family's (bm, bn) tile→PE granularity (the paper's per-element mapping is
-the ``bm = bn = 1`` special case, shared with ``os_array_matmul`` and the
-``ref`` oracles).
+The kernel consumes *pre-resolved* per-PE metadata: the packed
+``(rows, cols)`` int32 grid of :func:`~repro.core.engine.fault_meta_grid`,
+whose ``eff`` bit is ``faulty & ~repaired`` (the only case that leaves the
+fault in), already gathered through the RepairPlan's ``col_map`` — so a
+plan's remap costs nothing at run time.  The grid rides in SMEM as a scalar-
+prefetch operand (flattened, ``rows·cols`` words), and each grid cell reads
+its PE's word at ``(i % rows, j % cols)``.  The stuck-at mux is applied at
+the kernel family's (bm, bn) tile→PE granularity (the paper's per-element
+mapping is the ``bm = bn = 1`` special case, shared with ``os_array_matmul``
+and the ``ref`` oracles).
 
 Plan *pruning* is different: the engine zeroes pruned PEs' outputs at
 ELEMENT granularity (``out[i, j]`` → PE(i % rows, j % cols)), and the
@@ -58,32 +61,42 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.os_array_matmul import _stuck_at
+from repro.core.engine import META_BIT_MASK, META_EFF_SHIFT, META_VAL_SHIFT
 
 
-def _drain_tile(acc, bit, val, eff, pmask):
-    """Shared drain epilogue: stuck-at mux for effective faults (tile
-    granularity), then the element-granular prune AND-mask."""
-    bad = _stuck_at(acc, bit, val)
-    out = jnp.where(eff > 0, bad, acc)
-    raw = jax.lax.bitcast_convert_type(out, jnp.int32)
-    return jax.lax.bitcast_convert_type(raw & pmask, jnp.float32)
+def _drain_tile(acc, word, pmask):
+    """Shared drain epilogue for one tile whose PE has the packed meta
+    ``word``: an effective fault forces its stuck bit, a clean or repaired PE
+    passes the accumulator through (the scalar AND/OR pair of
+    ``engine.apply_fault_epilogue``), then the element-granular prune
+    AND-mask."""
+    stuck = jnp.left_shift(jnp.int32(1), word & META_BIT_MASK)
+    eff = ((word >> META_EFF_SHIFT) & 1) > 0
+    one = ((word >> META_VAL_SHIFT) & 1) > 0
+    and_m = jnp.where(eff & ~one, ~stuck, jnp.int32(-1))
+    or_m = jnp.where(eff & one, stuck, jnp.int32(0))
+    raw = jax.lax.bitcast_convert_type(acc, jnp.int32)
+    return jax.lax.bitcast_convert_type(((raw & and_m) | or_m) & pmask, jnp.float32)
+
+
+def _pe_word(meta_ref, i, j, rows: int, cols: int):
+    """The packed meta word of the PE that owns output tile (i, j)."""
+    return meta_ref[(i % rows) * cols + j % cols]
 
 
 def _prune_spec(mask_shape, bm: int, bn: int, batched: bool):
     """BlockSpec for the prune mask: a (bm, bn) periodic tile is broadcast
     to every grid cell; a full (m, n) mask is read per-tile."""
+    tile = mask_shape == (bm, bn)
     if batched:
-        if mask_shape == (bm, bn):
-            return pl.BlockSpec((bm, bn), lambda b, i, j, k: (0, 0))
-        return pl.BlockSpec((bm, bn), lambda b, i, j, k: (i, j))
-    if mask_shape == (bm, bn):
-        return pl.BlockSpec((bm, bn), lambda i, j, k: (0, 0))
-    return pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))
+        return pl.BlockSpec((bm, bn), lambda b, i, j, k, meta: (0, 0) if tile else (i, j))
+    return pl.BlockSpec((bm, bn), lambda i, j, k, meta: (0, 0) if tile else (i, j))
 
 
-def _kernel(x_ref, w_ref, bit_ref, val_ref, eff_ref, pmask_ref, o_ref, acc_ref):
-    k = pl.program_id(2)
+def _kernel(meta_ref, x_ref, w_ref, pmask_ref, o_ref, acc_ref, *, rows, cols):
+    # program ids are read at top level: interpret mode cannot lower them
+    # inside a pl.when branch
+    i, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
@@ -97,78 +110,61 @@ def _kernel(x_ref, w_ref, bit_ref, val_ref, eff_ref, pmask_ref, o_ref, acc_ref):
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _drain():
-        o_ref[...] = _drain_tile(
-            acc_ref[...], bit_ref[0, 0], val_ref[0, 0], eff_ref[0, 0],
-            pmask_ref[...],
-        )
-
-
-def _tile_meta(grid_m: int, grid_n: int, rows: int, cols: int, *grids):
-    """AGU: pre-gather (rows, cols) per-PE metadata to kernel-grid shape so
-    each grid cell reads its own (1, 1) SMEM block — no dynamic indexing in
-    the kernel body."""
-    ti = jnp.arange(grid_m) % rows
-    tj = jnp.arange(grid_n) % cols
-    return tuple(g[ti[:, None], tj[None, :]].astype(jnp.int32) for g in grids)
+        word = _pe_word(meta_ref, i, j, rows, cols)
+        o_ref[...] = _drain_tile(acc_ref[...], word, pmask_ref[...])
 
 
 def _keep_all(bm: int, bn: int) -> jax.Array:
     return jnp.full((bm, bn), -1, jnp.int32)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("bm", "bn", "bk", "rows", "cols", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def ft_matmul(
     x: jax.Array,
     w: jax.Array,
-    pe_bit: jax.Array,
-    pe_val: jax.Array,
-    pe_eff: jax.Array,
+    meta: jax.Array,
     prune_mask: jax.Array | None = None,
     *,
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
-    rows: int = 32,
-    cols: int = 32,
     interpret: bool = False,
 ) -> jax.Array:
-    """Single-pass protected matmul.  ``pe_eff`` = faulty & ~repaired, a
-    (rows, cols) grid already plan-gathered by the caller; ``prune_mask`` is
-    an int32 AND-mask of shape (bm, bn) (periodic tile) or (m, n), or None
-    for no pruning."""
+    """Single-pass protected matmul.  ``meta`` is the packed (rows, cols)
+    int32 PE grid of ``engine.fault_meta_grid``, already plan-gathered;
+    ``prune_mask`` is an int32 AND-mask of shape (bm, bn) (periodic tile) or
+    (m, n), or None for no pruning."""
     m, kdim = x.shape
     _, n = w.shape
+    rows, cols = meta.shape
     assert m % bm == 0 and n % bn == 0 and kdim % bk == 0
     gm, gn, gk = m // bm, n // bn, kdim // bk
 
-    bit, val, eff = _tile_meta(gm, gn, rows, cols, pe_bit, pe_val, pe_eff)
     if prune_mask is None:
         prune_mask = _keep_all(bm, bn)
     assert prune_mask.shape in ((bm, bn), (m, n))
 
-    meta_spec = pl.BlockSpec((1, 1), lambda i, j, k: (i, j), memory_space=pltpu.SMEM)
-    return pl.pallas_call(
-        _kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(gm, gn, gk),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            meta_spec,
-            meta_spec,
-            meta_spec,
+            pl.BlockSpec((bm, bk), lambda i, j, k, meta: (i, k)),
+            pl.BlockSpec((bk, bn), lambda i, j, k, meta: (k, j)),
             _prune_spec(prune_mask.shape, bm, bn, batched=False),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, meta: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel, rows=rows, cols=cols),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
-    )(x, w, bit, val, eff, prune_mask)
+    )(meta.reshape(-1).astype(jnp.int32), x, w, prune_mask)
 
 
-def _kernel_batched(x_ref, w_ref, bit_ref, val_ref, eff_ref, pmask_ref, o_ref, acc_ref):
-    k = pl.program_id(3)
+def _kernel_batched(meta_ref, x_ref, w_ref, pmask_ref, o_ref, acc_ref, *, rows, cols):
+    i, j, k = pl.program_id(1), pl.program_id(2), pl.program_id(3)
 
     @pl.when(k == 0)
     def _init():
@@ -182,28 +178,20 @@ def _kernel_batched(x_ref, w_ref, bit_ref, val_ref, eff_ref, pmask_ref, o_ref, a
 
     @pl.when(k == pl.num_programs(3) - 1)
     def _drain():
-        o_ref[0] = _drain_tile(
-            acc_ref[...], bit_ref[0, 0], val_ref[0, 0], eff_ref[0, 0],
-            pmask_ref[...],
-        )
+        word = _pe_word(meta_ref, i, j, rows, cols)
+        o_ref[0] = _drain_tile(acc_ref[...], word, pmask_ref[...])
 
 
-@functools.partial(
-    jax.jit, static_argnames=("bm", "bn", "bk", "rows", "cols", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def ft_matmul_batched(
     x: jax.Array,
     w: jax.Array,
-    pe_bit: jax.Array,
-    pe_val: jax.Array,
-    pe_eff: jax.Array,
+    meta: jax.Array,
     prune_mask: jax.Array | None = None,
     *,
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
-    rows: int = 32,
-    cols: int = 32,
     interpret: bool = False,
 ) -> jax.Array:
     """Batched-weight protected matmul: ``x (E, M, K) @ w (E, K, N)`` with the
@@ -213,28 +201,28 @@ def ft_matmul_batched(
     repeats per expert)."""
     e, m, kdim = x.shape
     _, _, n = w.shape
+    rows, cols = meta.shape
     assert m % bm == 0 and n % bn == 0 and kdim % bk == 0
     gm, gn, gk = m // bm, n // bn, kdim // bk
 
-    bit, val, eff = _tile_meta(gm, gn, rows, cols, pe_bit, pe_val, pe_eff)
     if prune_mask is None:
         prune_mask = _keep_all(bm, bn)
     assert prune_mask.shape in ((bm, bn), (m, n))
 
-    meta_spec = pl.BlockSpec((1, 1), lambda b, i, j, k: (i, j), memory_space=pltpu.SMEM)
-    return pl.pallas_call(
-        _kernel_batched,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(e, gm, gn, gk),
         in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda b, i, j, k: (b, i, k)),
-            pl.BlockSpec((1, bk, bn), lambda b, i, j, k: (b, k, j)),
-            meta_spec,
-            meta_spec,
-            meta_spec,
+            pl.BlockSpec((1, bm, bk), lambda b, i, j, k, meta: (b, i, k)),
+            pl.BlockSpec((1, bk, bn), lambda b, i, j, k, meta: (b, k, j)),
             _prune_spec(prune_mask.shape, bm, bn, batched=True),
         ],
-        out_specs=pl.BlockSpec((1, bm, bn), lambda b, i, j, k: (b, i, j)),
-        out_shape=jax.ShapeDtypeStruct((e, m, n), jnp.float32),
+        out_specs=pl.BlockSpec((1, bm, bn), lambda b, i, j, k, meta: (b, i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel_batched, rows=rows, cols=cols),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((e, m, n), jnp.float32),
         interpret=interpret,
-    )(x, w, bit, val, eff, prune_mask)
+    )(meta.reshape(-1).astype(jnp.int32), x, w, prune_mask)

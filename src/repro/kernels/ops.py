@@ -10,7 +10,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.engine import FaultState, HyCAConfig, _pe_grids, repaired_grid
+from repro.core.engine import (FaultState, HyCAConfig, _pe_grids, fault_meta_grid,
+                               repaired_grid)
 from repro.kernels import ref
 from repro.kernels.dppu_recompute import dppu_recompute, scatter_overwrite
 from repro.kernels.ft_matmul import ft_matmul
@@ -105,11 +106,9 @@ def hyca_protected_matmul_fused(
     interpret: bool | None = None,
 ):
     """Beyond-paper single-pass fused kernel (see ft_matmul.py)."""
-    bit, val, faulty, repaired = fault_grids(state, cfg.rows, cfg.cols, cfg.capacity)
-    eff = (faulty & ~repaired).astype(jnp.int32)
     return ft_matmul(
-        x, w, bit, val, eff, bm=bm, bn=bn, bk=bk, rows=cfg.rows,
-        cols=cfg.cols, interpret=_interp(interpret),
+        x, w, fault_meta_grid(state, cfg), bm=bm, bn=bn, bk=bk,
+        interpret=_interp(interpret),
     )
 
 

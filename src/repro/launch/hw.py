@@ -1,8 +1,33 @@
-"""Target-hardware constants (TPU v5e) for the roofline analysis."""
+"""Published per-chip peaks, keyed by ``jax.Device.device_kind``.
 
-PEAK_FLOPS_BF16 = 197e12       # per chip, bf16
-HBM_BW = 819e9                 # bytes/s per chip
-ICI_BW = 50e9                  # bytes/s per link (~)
-CHIPS_PER_POD = 256            # 16 x 16
-VMEM_BYTES = 128 * 2**20       # ~128 MiB VMEM per chip
-HBM_BYTES = 16 * 2**30         # 16 GiB HBM per chip
+TPU v5e ("TPU v5 lite" as JAX reports it): Google Cloud documentation,
+"TPU v5e" system architecture table — 197 TFLOP/s bf16, 819 GB/s of HBM
+bandwidth, 1,600 Gbit/s of inter-chip interconnect per chip over its four
+links of the 2D torus.  A device that is not in the table is an error, not
+a default.
+"""
+from __future__ import annotations
+
+import dataclasses
+
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    bf16_flops: float      # FLOP/s
+    hbm_bw: float          # bytes/s
+    ici_bw: float          # bytes/s of ONE inter-chip link: a ring along one
+                           # mesh axis sends over one link per direction
+
+
+PEAKS: dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(bf16_flops=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8 / 4),
+}
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known: {sorted(PEAKS)}"
+        ) from None

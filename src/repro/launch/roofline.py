@@ -1,8 +1,11 @@
 """§Roofline: three-term analysis per (arch × shape) on the single-pod mesh.
 
-    compute term    = HLO_FLOPs_corrected / PEAK_FLOPS_BF16      [s]
-    memory term     = HLO_bytes_corrected / HBM_BW               [s]
-    collective term = collective_wire_bytes / ICI_BW             [s]
+    compute term    = HLO_FLOPs_corrected / peaks.bf16_flops     [s]
+    memory term     = HLO_bytes_corrected / peaks.hbm_bw         [s]
+    collective term = collective_wire_bytes / peaks.ici_bw       [s]
+
+with ``peaks`` the published table entry (launch.hw) of the chip the
+single-pod roofline models, TPU v5e.
 
 All three use *per-device* quantities from the trip-count-corrected probes
 (launch.probes; cost_analysis counts a while body once, so production scans
@@ -22,10 +25,11 @@ import os
 
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.shapes import SHAPES, ShapeCell, applicable
-from repro.launch.hw import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.hw import peaks
 from repro.models.lm import LMConfig
 
 N_DEV = 256  # single-pod roofline (16 x 16)
+DEVICE_KIND = "TPU v5 lite"  # the chip of that pod (JAX's name for TPU v5e)
 
 
 def _attn_flops_fwd(cfg: LMConfig, tokens: int, seq: int, causal: bool = True) -> float:
@@ -86,6 +90,7 @@ def _advice(dominant: str, rec: dict, cfg: LMConfig, cell: ShapeCell) -> str:
 
 
 def analyse(probes_dir: str) -> list[dict]:
+    hw = peaks(DEVICE_KIND)
     rows = []
     for path in sorted(glob.glob(os.path.join(probes_dir, "*.json"))):
         rec = json.load(open(path))
@@ -96,15 +101,15 @@ def analyse(probes_dir: str) -> list[dict]:
         cfg, cell = get_config(arch), SHAPES[shape]
         t = rec["total"]
         terms = {
-            "compute": max(t["flops"], 0.0) / PEAK_FLOPS_BF16,
-            "memory": max(t["bytes"], 0.0) / HBM_BW,
-            "collective": max(t["wire_bytes"], 0.0) / ICI_BW,
+            "compute": max(t["flops"], 0.0) / hw.bf16_flops,
+            "memory": max(t["bytes"], 0.0) / hw.hbm_bw,
+            "collective": max(t["wire_bytes"], 0.0) / hw.ici_bw,
         }
         dominant = max(terms, key=terms.get)
         bound = max(terms.values())
         mf = model_flops(cfg, cell)
         mf_dev = mf / N_DEV
-        ideal = mf_dev / PEAK_FLOPS_BF16
+        ideal = mf_dev / hw.bf16_flops
         rows.append({
             "arch": arch, "shape": shape, "status": "ok",
             "compute_s": terms["compute"], "memory_s": terms["memory"],

@@ -65,13 +65,15 @@ def make_decode(cfg: LMConfig, mesh: Mesh, params_shapes: Any, cache_shapes: Any
 # CLI — thin front-end over repro.serving (the fault-aware runtime)
 # --------------------------------------------------------------------------- #
 def main(argv=None):
-    from repro.configs import get_smoke_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serving import FaultTolerantServer, ServerConfig
 
     ap = argparse.ArgumentParser(
-        description="Fault-aware continuous-batching inference server (smoke scale)."
+        description="Fault-aware continuous-batching inference server."
     )
     ap.add_argument("--arch", default="qwen1.5-0.5b")
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced config (CPU); default: the published widths")
     ap.add_argument("--slots", type=int, default=4, help="decode slots (max batch)")
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--prompt-len", type=int, default=8)
@@ -136,9 +138,11 @@ def main(argv=None):
                          "the run finishes (lets an external scraper catch "
                          "the final state — the CI obs-smoke lane does)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = ServerConfig(
-        arch=args.arch, n_slots=args.slots, smax=args.prompt_len + args.gen + 2,
+        arch=args.arch, smoke=args.smoke, n_slots=args.slots,
+        smax=args.prompt_len + args.gen + 2,
         mode=args.mode, rows=args.rows, cols=args.cols, dppu_size=args.dppu,
         protect_fraction=args.protect_fraction, dispatch=args.dispatch,
         scan_block=args.scan_block, fault_rate=args.fault_rate, seed=args.seed,
@@ -152,7 +156,7 @@ def main(argv=None):
         if args.mode == "protected":
             server.manager.bist()
 
-    lm = get_smoke_config(args.arch)
+    lm = server.lm
     rng = np.random.default_rng(args.seed)
     trace = [
         {

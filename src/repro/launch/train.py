@@ -31,7 +31,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.engine import FaultState, HyCAConfig
-from repro.core.ftcontext import FTContext, ProtectPolicy, build_ftcontext
+from repro.core.ftcontext import FTContext, ProtectPolicy, build_ftcontext, fused_backend
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.dist.sharding import (DEFAULT_RULES, DP_RULES, EP_RULES, named,
     param_specs, resolve_spec, use_mesh, use_rules, zero1_specs)
@@ -148,7 +148,19 @@ def make_train_step(
     plans swap as traced data).  ``grad_mask`` — a pytree of broadcastable
     multipliers matching ``params``; gradients are masked before the
     optimizer so frozen parameter groups stay bit-identical.
+
+    ``hyca_dispatch="fused"`` on a TPU raises here: its Pallas kernel
+    (``pallas_call``) has no JVP or transpose rule, so ``jax.grad`` cannot
+    differentiate the protected forward.  Off the TPU the fused dispatch is
+    the jnp formulation and trains.
     """
+    if hyca is not None and tc.hyca_mode != "off" and tc.hyca_dispatch == "fused" \
+            and fused_backend() == "pallas":
+        raise ValueError(
+            "hyca_dispatch='fused' cannot train on a TPU: the Pallas kernel "
+            "has no JVP or transpose rule, so jax.grad cannot differentiate "
+            "the protected forward; train with hyca_dispatch='twopass'"
+        )
     rules = {"dp": DP_RULES, "ep": EP_RULES}.get(profile, DEFAULT_RULES)
     sspec = state_specs(state_shapes, mesh, profile)
     bspec = batch_specs(batch_shapes, mesh, profile)
@@ -238,6 +250,7 @@ def make_train_step(
 def main(argv=None):
     from repro.checkpoint.store import CheckpointManager
     from repro.configs import get_config, get_smoke_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
 
     ap = argparse.ArgumentParser()
@@ -261,6 +274,7 @@ def main(argv=None):
                          "and a final-summary gauge file to PATH.prom "
                          "(docs/observability.md)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     tc = TrainConfig(

@@ -54,7 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_smoke_config
+from repro.configs import get_config, get_smoke_config
 from repro.core.engine import FaultState, HyCAConfig, empty_fault_state, identity_plan
 from repro.core.ftcontext import ProtectPolicy, build_ftcontext
 from repro.core.redundancy import DPPUConfig
@@ -71,6 +71,9 @@ from repro.serving.scheduler import ContinuousBatchingScheduler
 @dataclasses.dataclass(frozen=True)
 class ServerConfig:
     arch: str = "qwen1.5-0.5b"
+    # model size: the registry's reduced smoke config (CPU tests, CI) or,
+    # with smoke=False, the arch's published widths (configs/<arch>.config)
+    smoke: bool = True
     n_slots: int = 4
     smax: int = 96                 # KV capacity per slot
     mode: str = "protected"        # off | protected | unprotected
@@ -132,7 +135,7 @@ class ModelBundle:
 
     def __init__(self, cfg: ServerConfig, lm: LMConfig | None = None):
         self.cfg = cfg
-        self.lm = lm or get_smoke_config(cfg.arch)
+        self.lm = lm or (get_smoke_config if cfg.smoke else get_config)(cfg.arch)
         self.hyca = cfg.hyca()
         self.params = init_params(jax.random.key(cfg.seed), self.lm)
         self.max_faults = cfg.rows * cfg.cols

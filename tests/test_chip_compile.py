@@ -1,0 +1,98 @@
+"""The TPU compiler accepts the main path's kernels at real widths.
+
+Interpret mode accepts blocks Mosaic refuses (``tests/test_ft_fused.py``
+runs ``(1, 1, k)`` element blocks), so these tests compile for a described
+``v5e:2x2`` — nothing runs, no chip is needed — and check that the compiled
+program holds the Pallas kernel (``tpu_custom_call``):
+
+  * ``ft_matmul`` at qwen1.5-0.5b's decode and prefill projection shapes,
+    with no prune mask, a full ``(m, n)`` mask and a periodic tile;
+  * ``ft_matmul_batched`` at deepseek-moe-16b's expert shapes (64 experts,
+    d_model 2048, d_expert 1408);
+  * ``probe_check`` at the server's probe shapes (8x8 and 32x32 arrays) and
+    at K > window.
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU library, and the test workers all import this
+file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.dppu_recompute import probe_check
+from repro.kernels.ft_matmul import ft_matmul, ft_matmul_batched
+
+ROWS = COLS = 32
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of these tests
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_kernel(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("m,k,n,block,mask", [
+    (8, 1024, 2816, (8, 128, 128), None),          # decode ffn up/gate
+    (8, 2816, 1024, (8, 128, 128), "full"),        # decode ffn down, plan active
+    (8, 1024, 152064, (8, 128, 128), None),        # decode LM head (padded vocab)
+    (512, 1024, 2816, (128, 128, 128), "tile"),    # prefill 8 x 64 tokens, plan active
+])
+def test_ft_matmul_compiles(one_chip, m, k, n, block, mask):
+    bm, bn, bk = block
+    mask_shape = {"full": (m, n), "tile": (bm, bn)}.get(mask)
+    args = [_sds((m, k), jnp.float32, one_chip), _sds((k, n), jnp.float32, one_chip),
+            _sds((ROWS, COLS), jnp.int32, one_chip)]
+    if mask_shape is not None:
+        args.append(_sds(mask_shape, jnp.int32, one_chip))
+    _assert_kernel(lambda *a: ft_matmul(*a, bm=bm, bn=bn, bk=bk), *args)
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)])
+def test_ft_matmul_batched_compiles(one_chip, k, n):
+    e, m = 64, 8
+    _assert_kernel(
+        lambda x, w, meta: ft_matmul_batched(x, w, meta, bm=8, bn=128, bk=128),
+        _sds((e, m, k), jnp.float32, one_chip), _sds((e, k, n), jnp.float32, one_chip),
+        _sds((ROWS, COLS), jnp.int32, one_chip),
+    )
+
+
+@pytest.mark.parametrize("block,k,cols", [
+    (1, 8, 8),      # 8x8 serving array, one grid row per scan step
+    (1, 8, 32),     # the paper's 32x32 array
+    (32, 8, 32),    # a whole-array sweep block
+    (1, 16, 32),    # K > window
+    (4, 256, 32),   # K a multiple of the 128-lane panel
+])
+def test_probe_check_compiles(one_chip, block, k, cols):
+    _assert_kernel(
+        probe_check,
+        _sds((block, k), jnp.int32, one_chip), _sds((k, cols), jnp.int32, one_chip),
+        _sds((block, cols), jnp.int32, one_chip),
+    )
