@@ -238,26 +238,31 @@ class FTContext:
         The clean accumulate stays in the caller's layout (no pre-reshape),
         so it lowers to the identical XLA dot as the unprotected path —
         required for the bit-exact protected==off invariant.
+
+        Every operation of the call (casts, pads, fault grid, kernel, slice)
+        runs under ``jax.named_scope(site)``, so the device trace names it
+        after its site; a scope changes metadata only.
         """
-        if self._obs_record is not None:
-            protected = self.protects(site) and self.dispatch != "plain"
-            self._obs_record(
-                site=site, m=math.prod(x.shape[:-1]), n=int(w.shape[-1]),
-                count=1, dispatch=self.dispatch if protected else "plain",
-                protected=protected,
-            )
-        if not self.protects(site):
-            return jnp.matmul(x, w)
-        plan = self._plan_for(site)
-        if self.dispatch == "plain":
-            out = jnp.matmul(x, w)
-        elif self.dispatch == "twopass":
-            out = hyca_matmul(x, w, self.state, cfg=self.hyca, plan=plan)
-        elif self.dispatch == "fused":
-            out = self._fused(x, w, plan, site=site)
-        else:
-            raise ValueError(f"unknown dispatch {self.dispatch!r}; known: {DISPATCHES}")
-        return out.astype(x.dtype)
+        with jax.named_scope(site):
+            if self._obs_record is not None:
+                protected = self.protects(site) and self.dispatch != "plain"
+                self._obs_record(
+                    site=site, m=math.prod(x.shape[:-1]), n=int(w.shape[-1]),
+                    count=1, dispatch=self.dispatch if protected else "plain",
+                    protected=protected,
+                )
+            if not self.protects(site):
+                return jnp.matmul(x, w)
+            plan = self._plan_for(site)
+            if self.dispatch == "plain":
+                out = jnp.matmul(x, w)
+            elif self.dispatch == "twopass":
+                out = hyca_matmul(x, w, self.state, cfg=self.hyca, plan=plan)
+            elif self.dispatch == "fused":
+                out = self._fused(x, w, plan, site=site)
+            else:
+                raise ValueError(f"unknown dispatch {self.dispatch!r}; known: {DISPATCHES}")
+            return out.astype(x.dtype)
 
     def abft_matmul(
         self, x: jax.Array, w: jax.Array, *, site: str, wc: jax.Array | None = None
@@ -306,25 +311,27 @@ class FTContext:
 
         The spec is validated *first* (unsupported specs raise the same
         clear error on every dispatch path, before any shape indexing).
+        Like :meth:`matmul`, the call runs under ``jax.named_scope(site)``.
         """
         if spec not in EINSUM_SPECS:
             raise ValueError(
                 f"FTContext.einsum supports the expert-matmul patterns "
                 f"{EINSUM_SPECS} only, got {spec!r}"
             )
-        if self._obs_record is not None:
-            protected = self.protects(site) and self.dispatch != "plain"
-            self._obs_record(
-                site=site, m=x.shape[0] * x.shape[2], n=int(w.shape[-1]),
-                count=x.shape[1], dispatch=self.dispatch if protected else "plain",
-                protected=protected,
-            )
-        if not self.protects(site) or self.dispatch == "plain":
-            return jnp.einsum(spec, x, w)
-        plan = self._plan_for(site)
-        if self.dispatch == "fused":
-            return self._fused_einsum(spec, x, w, plan, site=site).astype(x.dtype)
-        return self._einsum_twopass(spec, x, w, plan).astype(x.dtype)
+        with jax.named_scope(site):
+            if self._obs_record is not None:
+                protected = self.protects(site) and self.dispatch != "plain"
+                self._obs_record(
+                    site=site, m=x.shape[0] * x.shape[2], n=int(w.shape[-1]),
+                    count=x.shape[1], dispatch=self.dispatch if protected else "plain",
+                    protected=protected,
+                )
+            if not self.protects(site) or self.dispatch == "plain":
+                return jnp.einsum(spec, x, w)
+            plan = self._plan_for(site)
+            if self.dispatch == "fused":
+                return self._fused_einsum(spec, x, w, plan, site=site).astype(x.dtype)
+            return self._einsum_twopass(spec, x, w, plan).astype(x.dtype)
 
     def _einsum_twopass(self, spec: str, x, w, plan: RepairPlan | None):
         b, e, c, d = x.shape

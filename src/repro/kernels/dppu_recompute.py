@@ -80,6 +80,7 @@ def dppu_recompute(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((f, bm, bn), jnp.float32),
         interpret=interpret,
+        name="dppu_recompute",
     )(trow, tcol, x, w)
 
 
@@ -175,6 +176,7 @@ def probe_check(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((block, cols), jnp.int32),
         interpret=interpret,
+        name="probe_check",
     )(px.astype(jnp.int32), pw.astype(jnp.int32), ar.astype(jnp.int32))
 
 

@@ -160,6 +160,7 @@ def ft_matmul(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
+        name="ft_matmul",
     )(meta.reshape(-1).astype(jnp.int32), x, w, prune_mask)
 
 
@@ -225,4 +226,5 @@ def ft_matmul_batched(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((e, m, n), jnp.float32),
         interpret=interpret,
+        name="ft_matmul_batched",
     )(meta.reshape(-1).astype(jnp.int32), x, w, prune_mask)

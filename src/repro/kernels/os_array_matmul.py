@@ -97,4 +97,5 @@ def os_array_matmul(
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="os_array_matmul",
     )(x, w, bit, val, faulty)

@@ -184,7 +184,7 @@ def main(argv=None):
 
     httpd = None
     if args.metrics_port is not None:
-        from repro.obs.export import histograms_text, prometheus_text
+        from repro.obs.export import gc_text, histograms_text, prometheus_text
         from repro.obs.httpd import MetricsServer
 
         def _render_prom():
@@ -193,6 +193,7 @@ def main(argv=None):
                 prometheus_text(server.metrics.summary(
                     counters=server.counters_host()), labels=labels)
                 + histograms_text(server.metrics.latency_lists(), labels=labels)
+                + gc_text(labels=labels)
             )
 
         httpd = MetricsServer(_render_prom, port=args.metrics_port)

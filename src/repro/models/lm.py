@@ -159,9 +159,11 @@ def _norm(x, p, cfg: LMConfig):
 
 
 def _cast(tree, dtype):
-    return jax.tree.map(
-        lambda a: a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating) else a, tree
-    )
+    # named so that the device trace can tell the per-step weight cast apart
+    with jax.named_scope("weights.cast"):
+        return jax.tree.map(
+            lambda a: a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating) else a, tree
+        )
 
 
 def _remat(f, cfg: LMConfig):

@@ -1,6 +1,12 @@
 """repro.obs — unified observability for the fault-tolerant runtime.
 
-Six layers (docs/observability.md):
+Seven layers (docs/observability.md):
+
+  * :mod:`repro.obs.host` — program spans on the profiler's clock:
+    :func:`span` wraps ``jax.profiler.TraceAnnotation`` under the ``hyca.``
+    prefix (the server step, the fault scan, decode feed / dispatch /
+    sample), and a ``gc.callbacks`` hook spans and counts the Python
+    collector's pauses.
 
   * :mod:`repro.obs.counters` — device-side FT counters: a :class:`Counters`
     pytree carried as an optional FTContext leaf, accumulated under jit from
@@ -45,13 +51,14 @@ from repro.obs.events import (  # noqa: F401
     detection_records,
     repair_records,
 )
-from repro.obs.export import prometheus_text, write_metrics_out  # noqa: F401
+from repro.obs.export import gc_text, prometheus_text, write_metrics_out  # noqa: F401
 from repro.obs.fallbacks import (  # noqa: F401
     fallback_summary,
     record_site_fallback,
     reset_site_fallbacks,
     site_fallback_total,
 )
+from repro.obs.host import install_gc_hook, span  # noqa: F401
 from repro.obs.series import (  # noqa: F401
     SeriesBuffer,
     load_series,

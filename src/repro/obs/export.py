@@ -21,6 +21,11 @@ Latency *distributions* (TTFT, detection, repair) export as Prometheus
 histograms (:func:`histogram_text`): cumulative ``_bucket{le="..."}``
 counts plus ``_sum``/``_count``, step-domain buckets — enough for a
 dashboard to plot percentiles without the raw event log.
+
+The Python collector's pauses (:mod:`repro.obs.host`'s hook, installed when
+a server is built) export as two counters labelled by generation,
+``hyca_python_gc_seconds_total`` and ``hyca_python_gc_collections_total``
+(:func:`gc_text`).
 """
 from __future__ import annotations
 
@@ -137,6 +142,23 @@ def histograms_text(hists: dict[str, list], *, prefix: str = "hyca",
     )
 
 
+def gc_text(*, prefix: str = "hyca", labels: dict | None = None) -> str:
+    """The collector hook's counters as Prometheus counters, one sample per
+    generation; empty when the hook is not installed in this process."""
+    from repro.obs.host import gc_hook
+
+    hook = gc_hook()
+    if hook is None:
+        return ""
+    lines = []
+    for key, values in hook.counters().items():
+        full = _metric_name(prefix, "python", key)
+        lines.append(f"# TYPE {full} counter")
+        lines += [f"{full}{_label_str(labels, {'generation': g})} {v:g}"
+                  for g, v in enumerate(values)]
+    return "\n".join(lines) + "\n"
+
+
 def write_metrics_out(path: str, summary: dict, log=None, *,
                       prefix: str = "hyca", labels: dict | None = None,
                       histograms: dict[str, list] | None = None) -> tuple[str, str]:
@@ -162,4 +184,5 @@ def write_metrics_out(path: str, summary: dict, log=None, *,
         f.write(prometheus_text(summary, prefix=prefix, labels=labels))
         if histograms:
             f.write(histograms_text(histograms, prefix=prefix, labels=labels))
+        f.write(gc_text(prefix=prefix, labels=labels))
     return path, prom_path

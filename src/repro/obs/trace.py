@@ -21,6 +21,10 @@ events by entity id into :class:`Trace` objects, each a tree of
     :func:`~repro.obs.events.repair_records`), so a span timeline and the
     summary's ``detect_latency_*`` / ``repair_latency_*`` agree exactly.
 
+Request spans also carry ``start_ts`` / ``end_ts``: the wall clock
+(``time.time``) of the events that bound them, from the ``ts`` every event
+already records; fault spans leave them ``None``.
+
 Ids are deterministic content hashes (sha1 of the entity key), OTLP-shaped:
 128-bit ``trace_id``, 64-bit ``span_id``, ``parent_span_id`` linking the
 tree.  Export is JSONL (one span object per line, :func:`write_spans`);
@@ -64,7 +68,8 @@ def span_id(tid: str, name: str) -> str:
 @dataclasses.dataclass(frozen=True)
 class Span:
     """One lifecycle phase of one entity, in the step domain (OTLP-style:
-    steps stand in for wall-clock nanos — the simulation's time axis)."""
+    steps stand in for wall-clock nanos — the simulation's time axis), with
+    the wall-clock seconds of its bounding events where they are known."""
 
     trace_id: str
     span_id: str
@@ -74,14 +79,23 @@ class Span:
     end_step: int | None
     attributes: dict[str, Any]
     status: str = "ok"
+    start_ts: float | None = None
+    end_ts: float | None = None
 
     def to_json(self) -> dict:
         return {
             "trace_id": self.trace_id, "span_id": self.span_id,
             "parent_span_id": self.parent_span_id, "name": self.name,
             "start_step": self.start_step, "end_step": self.end_step,
+            "start_ts": self.start_ts, "end_ts": self.end_ts,
             "status": self.status, "attributes": self.attributes,
         }
+
+    @property
+    def duration_s(self) -> float | None:
+        if self.start_ts is None or self.end_ts is None:
+            return None
+        return self.end_ts - self.start_ts
 
     @property
     def duration_steps(self) -> int | None:
@@ -113,10 +127,11 @@ def _as_log(events) -> EventLog:
 
 
 def _child(tid: str, root_sid: str, name: str, start, end,
-           attributes: dict, status: str = "ok") -> Span:
+           attributes: dict, status: str = "ok", start_ts=None, end_ts=None) -> Span:
     return Span(trace_id=tid, span_id=span_id(tid, name),
                 parent_span_id=root_sid, name=name, start_step=start,
-                end_step=end, attributes=attributes, status=status)
+                end_step=end, attributes=attributes, status=status,
+                start_ts=start_ts, end_ts=end_ts)
 
 
 # --------------------------------------------------------------------------- #
@@ -149,6 +164,8 @@ def request_traces(events) -> list[Trace]:
         start = enq.step if enq else min(
             (e.step for e in per.values() if e.step is not None), default=None)
         end = comp.step if comp else None
+        start_ts = enq.ts if enq else min(e.ts for e in per.values())
+        end_ts = comp.ts if comp else None
         attrs: dict[str, Any] = {"rid": rid}
         if enq:
             attrs["prompt_len"] = enq.data["prompt_len"]
@@ -159,13 +176,15 @@ def request_traces(events) -> list[Trace]:
             attrs["ttft_steps"] = ftok.step - start
         spans = [Span(trace_id=tid, span_id=root_sid, parent_span_id=None,
                       name="request", start_step=start, end_step=end,
-                      attributes=attrs, status=status)]
+                      attributes=attrs, status=status,
+                      start_ts=start_ts, end_ts=end_ts)]
 
         # queue: enqueue -> admission, or -> death while still queued
         q_end = adm.step if adm else end
         spans.append(_child(
             tid, root_sid, "queue", start, q_end, {"rid": rid},
-            status="ok" if adm else status))
+            status="ok" if adm else status,
+            start_ts=start_ts, end_ts=adm.ts if adm else end_ts))
         if adm:
             slot = adm.data["slot"]
             # prefill: admission -> first token (or death mid-prefill)
@@ -173,11 +192,13 @@ def request_traces(events) -> list[Trace]:
             spans.append(_child(
                 tid, root_sid, "prefill", adm.step, p_end,
                 {"rid": rid, "slot": slot},
-                status="ok" if ftok else status))
+                status="ok" if ftok else status,
+                start_ts=adm.ts, end_ts=ftok.ts if ftok else end_ts))
             if ftok:
                 spans.append(_child(
                     tid, root_sid, "decode", ftok.step, end,
-                    {"rid": rid, "slot": slot}, status=status))
+                    {"rid": rid, "slot": slot}, status=status,
+                    start_ts=ftok.ts, end_ts=end_ts))
         traces.append(Trace(trace_id=tid, entity=entity, spans=tuple(spans)))
     return traces
 
@@ -290,6 +311,14 @@ def validate_span(obj: dict) -> None:
     s, e = obj["start_step"], obj["end_step"]
     if s is not None and e is not None and e < s:
         raise ValueError(f"span {obj['name']!r}: end_step {e} < start_step {s}")
+    # wall-clock bounds: optional (span files written before they existed)
+    for field in ("start_ts", "end_ts"):
+        v = obj.get(field)
+        if v is not None and (not isinstance(v, (int, float)) or isinstance(v, bool)):
+            raise ValueError(f"{field} must be a number or null, got {v!r}")
+    s, e = obj.get("start_ts"), obj.get("end_ts")
+    if s is not None and e is not None and e < s:
+        raise ValueError(f"span {obj['name']!r}: end_ts {e} < start_ts {s}")
     if obj["status"] not in SPAN_STATUSES:
         raise ValueError(f"status must be one of {SPAN_STATUSES}, got {obj['status']!r}")
     if not isinstance(obj["attributes"], dict):

@@ -62,6 +62,7 @@ from repro.core.scan import (
     probe_operands,
     scan_probe_block,
 )
+from repro.obs.host import span
 
 HEALTHY, SUSPECT, CONFIRMED, REPAIRED, RETIRED = "healthy", "suspect", "confirmed", "repaired", "retired"
 # repro.repair outcome: an over-capacity confirmed fault whose PE column is
@@ -417,26 +418,38 @@ class FaultManager:
         """One batched probe step (call once per decode step): checks
         ``scan_block`` grid rows × all columns against the complementary
         ±probe pair in a single jitted call.  Returns (block all-clean,
-        (first row, one-past-last row) of the scanned block)."""
+        (first row, one-past-last row) of the scanned block).
+
+        Profiler spans (repro.obs.host): ``hyca.fault.scan.sync`` around
+        every device→host readback (with ``_sync``'s fold of the hit
+        counters it reads), ``hyca.fault.scan.probe`` around the host probe
+        operands and the probe's dispatch."""
         block = self.engine.cfg.block_rows
-        sweep = int(self.scan_state.sweep)
-        r0 = int(self.scan_state.cursor) * block
-        px, pw = self.injector.probe_operands(sweep, self.cfg.probe_window)
-        # only the scanned block's rows are materialized and corrupted
-        px_b = px[r0 : r0 + block]
-        ar_b = self.injector.corrupted_probe(px_b, pw, row0=r0)
-        arn_b = self.injector.corrupted_probe(px_b, -pw, row0=r0)
-        self.scan_state, flags, _ = scan_probe_block(
-            self.engine, self.scan_state,
-            jnp.asarray(px_b), jnp.asarray(pw), jnp.asarray(ar_b), jnp.asarray(arn_b),
-        )
+        with span("fault.scan.sync"):
+            sweep = int(self.scan_state.sweep)
+            r0 = int(self.scan_state.cursor) * block
+        with span("fault.scan.probe"):
+            px, pw = self.injector.probe_operands(sweep, self.cfg.probe_window)
+            # only the scanned block's rows are materialized and corrupted
+            px_b = px[r0 : r0 + block]
+            ar_b = self.injector.corrupted_probe(px_b, pw, row0=r0)
+            arn_b = self.injector.corrupted_probe(px_b, -pw, row0=r0)
+            self.scan_state, flags, _ = scan_probe_block(
+                self.engine, self.scan_state,
+                jnp.asarray(px_b), jnp.asarray(pw), jnp.asarray(ar_b), jnp.asarray(arn_b),
+            )
         self.scans += 1
-        if int(self.scan_state.sweep) > sweep:
+        with span("fault.scan.sync"):
+            swept = int(self.scan_state.sweep) > sweep
+        if swept:
             self._emit("scan.sweep", sweep=sweep, steps=self.engine.cfg.steps_per_sweep)
         if self.cfg.abft:
             self.abft_check()
-        self._sync()
-        return not bool(np.asarray(flags).any()), (r0, r0 + block)
+        with span("fault.scan.sync"):
+            self._sync()
+        with span("fault.scan.sync"):
+            clean = not bool(np.asarray(flags).any())
+        return clean, (r0, r0 + block)
 
     def boot_scan(self, *, batched: bool = True) -> int:
         """Power-on scan: ``max_boot_sweeps`` whole-array sweeps.

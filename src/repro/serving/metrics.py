@@ -13,6 +13,9 @@ detection latency (injection → CONFIRMED step deltas — exact under chaos
 injection, where injection steps are known), suspect latency, repair
 latency, completed scan sweeps, and scan coverage.  Pass ``counters=`` (the
 host-folded repro.obs counter dict) to embed the device-side MAC accounting.
+The same log's wall-clock stamps give ``queue_wait_s_p90`` (enqueue →
+admission) and ``ttft_s_p90`` (enqueue → first token), in seconds, next to
+the step-domain latencies; both are None without a log or an observation.
 
 The wall clock starts lazily at the first ``record_step``, NOT at
 construction — bundle build + XLA compile time between constructing a
@@ -111,6 +114,23 @@ class ServingMetrics:
             if c.first_token_step is not None
         ]
 
+    def wall_latencies(self) -> dict[str, list[float]]:
+        """Per-request wall-clock seconds from the event log's request spans
+        (repro.obs.trace): ``queue_wait_s`` from enqueue to admission and
+        ``ttft_s`` from enqueue to the first token.  Empty without a log."""
+        out: dict[str, list[float]] = {"queue_wait_s": [], "ttft_s": []}
+        if self.log is None:
+            return out
+        from repro.obs.trace import request_traces  # deferred: trace imports events
+
+        for tr in request_traces(self.log):
+            by_name = {sp.name: sp for sp in tr.spans}
+            if "prefill" in by_name and by_name["queue"].duration_s is not None:
+                out["queue_wait_s"].append(by_name["queue"].duration_s)
+            if "decode" in by_name and tr.root.start_ts is not None:
+                out["ttft_s"].append(by_name["decode"].start_ts - tr.root.start_ts)
+        return out
+
     def latency_lists(self) -> dict[str, list[int]]:
         """Raw step-latency observations per metric — the histogram
         exporter's input (repro.obs.export.histograms_text); the same lists
@@ -161,6 +181,8 @@ class ServingMetrics:
             "slo_attainment_defined": bool(slo_requests),
             # same mean/p50/p95 treatment as the detect/repair latency blocks
             **latency_summary(ttft, "ttft"),
+            **{f"{k}_p90": float(np.percentile(v, 90)) if v else None
+               for k, v in self.wall_latencies().items()},
             "queue_depth_mean": float(np.mean([r.queue_depth for r in self.steps])) if self.steps else 0.0,
             "scan_steps": n_pe_scans,
             "scan_sweeps": n_pe_scans / sweep,

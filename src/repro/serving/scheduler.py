@@ -79,6 +79,14 @@ class ContinuousBatchingScheduler:
     def active(self) -> int:
         return sum(not s.free for s in self.slots)
 
+    def attended_positions(self) -> int:
+        """KV positions the next decode step attends, summed over active
+        slots: the fed token's position plus one."""
+        return sum(
+            s.pos + 1 if s.phase == PREFILL else s.request.prompt_len + len(s.generated)
+            for s in self.slots if not s.free
+        )
+
     def admit(self, queue: RequestQueue, step: int) -> tuple[list[Slot], list[CompletedRequest]]:
         """Fill free slots from the queue up to the effective capacity.
         Returns (admitted slots — their caches must be reset, rejections)."""

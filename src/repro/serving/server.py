@@ -60,6 +60,7 @@ from repro.core.ftcontext import ProtectPolicy, build_ftcontext
 from repro.core.redundancy import DPPUConfig
 from repro.models.lm import LMConfig, decode_step, init_cache, init_params
 from repro.obs.events import EventLog
+from repro.obs.host import install_gc_hook, span
 from repro.repair.plan import remap_plan
 from repro.repair.remap import weight_salience
 from repro.serving.fault_manager import FaultInjector, FaultManager, FaultManagerConfig
@@ -238,6 +239,8 @@ class FaultTolerantServer:
         # the manager; step() stamps the cursor, so injections and lifecycle
         # transitions carry serving-time steps (docs/observability.md)
         self.log = EventLog()
+        # the collector's pauses become hyca.python.gc spans and counters
+        install_gc_hook()
         self.counters = self.bundle.zero_counters() if cfg.counters else None
         self.series = None
         self._n_scan_steps = 0
@@ -363,40 +366,41 @@ class FaultTolerantServer:
         key = (self.manager.n_confirmed, self.manager.n_remapped)
         if self.manager.n_remapped == 0 or key == self._repair_key:
             return
-        self._repair_key = key
-        # plan ONLY the columns the manager actually REMAPPED: overflow past
-        # the max_remap_fraction budget is RETIRED (column-region discard),
-        # and pruning victims for discarded columns would double-charge the
-        # quality accounting
-        plan = remap_plan(
-            self.manager.confirmed_state, self.bundle.hyca, self.bundle.salience,
-            broken_cols=self.manager.remapped_cols,
-        )
-        params = None
-        if self.cfg.repair == "retrain" and self.cfg.retrain_steps > 0:
-            from repro.repair.retrain import RetrainConfig, retrain
-
-            params, report = retrain(
-                self.params, self.lm,
-                hyca=self.bundle.hyca,
-                state=self.manager.confirmed_state,
-                plan=plan,
-                rc=RetrainConfig(
-                    steps=self.cfg.retrain_steps,
-                    seq_len=min(32, self.cfg.smax),
-                    seed=self.cfg.seed,
-                ),
+        with span("repair"):
+            self._repair_key = key
+            # plan ONLY the columns the manager actually REMAPPED: overflow past
+            # the max_remap_fraction budget is RETIRED (column-region discard),
+            # and pruning victims for discarded columns would double-charge the
+            # quality accounting
+            plan = remap_plan(
+                self.manager.confirmed_state, self.bundle.hyca, self.bundle.salience,
+                broken_cols=self.manager.remapped_cols,
             )
-        self.apply_repair(plan=plan, params=params)
-        self.log.emit(
-            "repair.plan",
-            step=self.step_idx,
-            mode=self.cfg.repair,
-            n_remapped=self.manager.n_remapped,
-            remapped_cols=sorted(self.manager.remapped_cols),
-            quality_fraction=self.manager.quality_fraction,
-            retrained=params is not None,
-        )
+            params = None
+            if self.cfg.repair == "retrain" and self.cfg.retrain_steps > 0:
+                from repro.repair.retrain import RetrainConfig, retrain
+
+                params, report = retrain(
+                    self.params, self.lm,
+                    hyca=self.bundle.hyca,
+                    state=self.manager.confirmed_state,
+                    plan=plan,
+                    rc=RetrainConfig(
+                        steps=self.cfg.retrain_steps,
+                        seq_len=min(32, self.cfg.smax),
+                        seed=self.cfg.seed,
+                    ),
+                )
+            self.apply_repair(plan=plan, params=params)
+            self.log.emit(
+                "repair.plan",
+                step=self.step_idx,
+                mode=self.cfg.repair,
+                n_remapped=self.manager.n_remapped,
+                remapped_cols=sorted(self.manager.remapped_cols),
+                quality_fraction=self.manager.quality_fraction,
+                retrained=params is not None,
+            )
 
     @property
     def repair_events(self) -> list[dict]:
@@ -422,98 +426,115 @@ class FaultTolerantServer:
 
     # ------------------------------------------------------------------ #
     def step(self) -> list[CompletedRequest]:
+        """One server step.  Every phase runs under a ``hyca.*`` profiler
+        span (repro.obs.host) inside the root ``hyca.server.step``."""
         cfg = self.cfg
         step = self.step_idx
         self.log.step = step
         completed: list[CompletedRequest] = []
+        root = span("server.step", step=step)
+        with root:
+            # 1. hardware wearout
+            if cfg.mode != "off" and cfg.fault_rate > 0:
+                with span("fault.inject"):
+                    self.injector.step(cfg.fault_rate)
 
-        # 1. hardware wearout
-        if cfg.mode != "off" and cfg.fault_rate > 0:
-            self.injector.step(cfg.fault_rate)
+            # 2. one batched row-block scan step per decode step
+            scan_ok: bool | None = None
+            if cfg.mode == "protected":
+                with span("fault.scan"):
+                    scan_ok, _ = self.manager.scan_step()
 
-        # 2. one batched row-block scan step per decode step
-        scan_ok: bool | None = None
-        if cfg.mode == "protected":
-            scan_ok, _ = self.manager.scan_step()
+            # 2b. background repair hook: newly REMAPPED faults trigger a plan
+            # rebuild (and, in retrain mode, a budgeted fine-tune) — swapped
+            # into the running step as traced leaves, zero recompiles
+            self._maybe_repair()
 
-        # 2b. background repair hook: newly REMAPPED faults trigger a plan
-        # rebuild (and, in retrain mode, a budgeted fine-tune) — swapped into
-        # the running step as traced leaves, zero recompiles
-        self._maybe_repair()
+            with span("sched.admit"):
+                # 3. degraded capacity -> admission limit
+                eff = self._effective_slots()
+                self.scheduler.set_effective_slots(eff)
 
-        # 3. degraded capacity -> admission limit
-        eff = self._effective_slots()
-        self.scheduler.set_effective_slots(eff)
+                # 4. admission into freed slots
+                admitted, rejected = self.scheduler.admit(self.queue, step)
+                completed.extend(rejected)
+                for req in self.queue.drained_expired():
+                    completed.append(CompletedRequest(
+                        rid=req.rid, tokens=np.zeros(0, np.int32), prompt_len=req.prompt_len,
+                        arrival_step=req.arrival_step, admitted_step=None,
+                        first_token_step=None, finish_step=step, reason="expired",
+                        deadline_step=req.deadline_step,
+                    ))
+            if admitted:
+                with span("cache.reset"):
+                    for slot in admitted:
+                        self.cache = self.bundle.reset_fn(self.cache, jnp.int32(slot.index))
 
-        # 4. admission into freed slots (reset their KV cache slots)
-        admitted, rejected = self.scheduler.admit(self.queue, step)
-        completed.extend(rejected)
-        for req in self.queue.drained_expired():
-            completed.append(CompletedRequest(
-                rid=req.rid, tokens=np.zeros(0, np.int32), prompt_len=req.prompt_len,
-                arrival_step=req.arrival_step, admitted_step=None,
-                first_token_step=None, finish_step=step, reason="expired",
-                deadline_step=req.deadline_step,
-            ))
-        for slot in admitted:
-            self.cache = self.bundle.reset_fn(self.cache, jnp.int32(slot.index))
+            # 5. one batched decode over all slots
+            with span("decode.feed"):
+                positions = self.scheduler.attended_positions() if root.is_enabled() else None
+                feed = self.scheduler.plan_feed()
+                tok = jnp.asarray(feed)
+                fstate = self._current_fstate()
+            with span("decode.dispatch"):
+                if self.counters is not None:
+                    logits, self.cache, self.counters = self.bundle.step_fn(
+                        self.params, self.cache, tok, fstate, self.plan, self.counters,
+                    )
+                else:
+                    logits, self.cache = self.bundle.step_fn(
+                        self.params, self.cache, tok, fstate, self.plan,
+                    )
+            with span("decode.sample"):
+                sampled = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1), np.int32)
 
-        # 5. one batched decode over all slots
-        feed = self.scheduler.plan_feed()
-        if self.counters is not None:
-            logits, self.cache, self.counters = self.bundle.step_fn(
-                self.params, self.cache, jnp.asarray(feed), self._current_fstate(),
-                self.plan, self.counters,
-            )
-        else:
-            logits, self.cache = self.bundle.step_fn(
-                self.params, self.cache, jnp.asarray(feed), self._current_fstate(),
-                self.plan,
-            )
-        sampled = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1), np.int32)
+            # 6. advance requests
+            with span("sched.commit"):
+                n_active = self.scheduler.active
+                done = self.scheduler.commit(sampled, step)
+                completed.extend(done)
+                n_decode_tokens = self.scheduler.last_step_tokens
 
-        # 6. advance requests
-        n_active = self.scheduler.active
-        done = self.scheduler.commit(sampled, step)
-        completed.extend(done)
-        n_decode_tokens = self.scheduler.last_step_tokens
+            with span("metrics.record"):
+                self.metrics.record_step(StepRecord(
+                    step=step,
+                    active_slots=n_active,
+                    effective_slots=eff,
+                    queue_depth=self.queue.depth(),
+                    tokens_generated=int(n_decode_tokens),
+                    confirmed_faults=self.manager.n_confirmed,
+                    true_faults=self.injector.n_faults,
+                    surviving_cols=self.manager.surviving_cols,
+                    scan_ok=scan_ok,
+                    completed=len(completed),
+                    remapped=self.manager.n_remapped,
+                    quality_fraction=self.manager.quality_fraction,
+                ), completed)
+                if scan_ok is not None:
+                    self._n_scan_steps += 1
+                if self.series is not None:
+                    # every value is already host-resident (the StepRecord above
+                    # uses the same ones), so the series path adds zero host sync —
+                    # just one donated jitted ring append
+                    from repro.obs.series import record_step as _series_record
 
-        self.metrics.record_step(StepRecord(
-            step=step,
-            active_slots=n_active,
-            effective_slots=eff,
-            queue_depth=self.queue.depth(),
-            tokens_generated=int(n_decode_tokens),
-            confirmed_faults=self.manager.n_confirmed,
-            true_faults=self.injector.n_faults,
-            surviving_cols=self.manager.surviving_cols,
-            scan_ok=scan_ok,
-            completed=len(completed),
-            remapped=self.manager.n_remapped,
-            quality_fraction=self.manager.quality_fraction,
-        ), completed)
-        if scan_ok is not None:
-            self._n_scan_steps += 1
-        if self.series is not None:
-            # every value is already host-resident (the StepRecord above
-            # uses the same ones), so the series path adds zero host sync —
-            # just one donated jitted ring append
-            from repro.obs.series import record_step as _series_record
-
-            self.series = _series_record(self.series, {
-                "tokens": int(n_decode_tokens),
-                "queue_depth": self.queue.depth(),
-                "active": n_active,
-                "confirmed": self.manager.n_confirmed,
-                "effective_slots": eff,
-                "true_faults": self.injector.n_faults,
-                "surviving_cols": self.manager.surviving_cols,
-                "scan_coverage": min(
-                    1.0, self._n_scan_steps / max(self.metrics.steps_per_sweep, 1)),
-                "capacity_fraction": float(self.manager.capacity_fraction),
-                "quality_fraction": float(self.manager.quality_fraction),
-            })
-        self.step_idx += 1
+                    self.series = _series_record(self.series, {
+                        "tokens": int(n_decode_tokens),
+                        "queue_depth": self.queue.depth(),
+                        "active": n_active,
+                        "confirmed": self.manager.n_confirmed,
+                        "effective_slots": eff,
+                        "true_faults": self.injector.n_faults,
+                        "surviving_cols": self.manager.surviving_cols,
+                        "scan_coverage": min(
+                            1.0, self._n_scan_steps / max(self.metrics.steps_per_sweep, 1)),
+                        "capacity_fraction": float(self.manager.capacity_fraction),
+                        "quality_fraction": float(self.manager.quality_fraction),
+                    })
+                self.step_idx += 1
+            if positions is not None:
+                root.set_metadata(active=n_active, positions=positions,
+                                  tokens=int(n_decode_tokens), queue=self.queue.depth())
         return completed
 
     # ------------------------------------------------------------------ #
