@@ -250,6 +250,7 @@ class FTContext:
                     site=site, m=math.prod(x.shape[:-1]), n=int(w.shape[-1]),
                     count=1, dispatch=self.dispatch if protected else "plain",
                     protected=protected,
+                    operand_dtype=jnp.promote_types(x.dtype, w.dtype).name,
                 )
             if not self.protects(site):
                 return jnp.matmul(x, w)
@@ -325,6 +326,7 @@ class FTContext:
                     site=site, m=x.shape[0] * x.shape[2], n=int(w.shape[-1]),
                     count=x.shape[1], dispatch=self.dispatch if protected else "plain",
                     protected=protected,
+                    operand_dtype=jnp.promote_types(x.dtype, w.dtype).name,
                 )
             if not self.protects(site) or self.dispatch == "plain":
                 return jnp.einsum(spec, x, w)
@@ -344,12 +346,15 @@ class FTContext:
     # ------------------------------------------------------------------ #
     # fused dispatch
     # ------------------------------------------------------------------ #
-    def _block_for(self, m: int, n: int, k: int) -> tuple[int, int, int]:
-        if self.fused_block == "auto":
-            from repro.kernels.autotune import resolve_block
+    def _block_for(self, m: int, n: int, k: int, dtype) -> tuple[int, int, int]:
+        """The kernel block for one call: the autotune lookup keyed on the
+        operand dtype, or the explicit block checked against that dtype's
+        sublane tile (context build could only check the f32 one)."""
+        from repro.kernels.autotune import resolve_block, validate_fused_block
 
-            return resolve_block(m, n, k, dtype=jnp.float32, backend=self.fused_backend)
-        return self.fused_block
+        if self.fused_block == "auto":
+            return resolve_block(m, n, k, dtype=dtype, backend=self.fused_backend)
+        return validate_fused_block(self.fused_block, backend=self.fused_backend, dtype=dtype)
 
     def _prune_mask(self, plan: RepairPlan | None, meta: jax.Array,
                     bm: int, bn: int, mp: int, np_: int) -> jax.Array | None:
@@ -397,8 +402,10 @@ class FTContext:
         # col_map is a pre-kernel gather of the tiny (rows, cols) grids and
         # its element-granular prune mask zeroes inside the drain, so
         # plan-active decode costs zero extra output-sized HBM passes.  The
-        # stuck-at mux is at (bm, bn) tile→PE granularity; inputs are
-        # zero-padded to block multiples and the result sliced back.
+        # stuck-at mux is at (bm, bn) tile→PE granularity; inputs enter in
+        # their common dtype (bf16 serving stays bf16: the kernel multiplies
+        # into an f32 accumulator, and a bf16 product is exact in f32), are
+        # zero-padded to block multiples, and the result is sliced back.
         if jnp.issubdtype(x.dtype, jnp.integer) or jnp.issubdtype(w.dtype, jnp.integer):
             # the kernel accumulates f32; int datapaths keep the engine's
             # exact int32 stuck-at semantics via the two-pass path
@@ -409,10 +416,11 @@ class FTContext:
         x2, lead = _as_2d(x)
         m, k = x2.shape
         n = w.shape[-1]
-        bm, bn, bk = self._block_for(m, n, k)
+        dtype = jnp.promote_types(x.dtype, w.dtype)
+        bm, bn, bk = self._block_for(m, n, k, dtype)
         mp, kp, np_ = -(-m // bm) * bm, -(-k // bk) * bk, -(-n // bn) * bn
-        xp = jnp.pad(x2.astype(jnp.float32), ((0, mp - m), (0, kp - k)))
-        wp = jnp.pad(w.astype(jnp.float32), ((0, kp - k), (0, np_ - n)))
+        xp = jnp.pad(x2.astype(dtype), ((0, mp - m), (0, kp - k)))
+        wp = jnp.pad(w.astype(dtype), ((0, kp - k), (0, np_ - n)))
         meta = fault_meta_grid(self.state, cfg, plan)
         out = ft_matmul(
             xp, wp, meta, self._prune_mask(plan, meta, bm, bn, mp, np_),
@@ -447,10 +455,11 @@ class FTContext:
 
         xe = x.transpose(1, 0, 2, 3).reshape(e, b * c, d)
         m, kdim = b * c, d
-        bm, bn, bk = self._block_for(m, n, kdim)
+        dtype = jnp.promote_types(x.dtype, w.dtype)
+        bm, bn, bk = self._block_for(m, n, kdim, dtype)
         mp, kp, np_ = -(-m // bm) * bm, -(-kdim // bk) * bk, -(-n // bn) * bn
-        xp = jnp.pad(xe.astype(jnp.float32), ((0, 0), (0, mp - m), (0, kp - kdim)))
-        wp = jnp.pad(w.astype(jnp.float32), ((0, 0), (0, kp - kdim), (0, np_ - n)))
+        xp = jnp.pad(xe.astype(dtype), ((0, 0), (0, mp - m), (0, kp - kdim)))
+        wp = jnp.pad(w.astype(dtype), ((0, 0), (0, kp - kdim), (0, np_ - n)))
         meta = fault_meta_grid(self.state, cfg, plan)
         out = ft_matmul_batched(
             xp, wp, meta, self._prune_mask(plan, meta, bm, bn, mp, np_),

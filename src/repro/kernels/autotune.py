@@ -1,9 +1,11 @@
 """Block-size autotuning for the fused ``ft_matmul`` kernel family.
 
-The right (bm, bn, bk) depends on the matmul shape, dtype, and backend — a
-decode-time (4, 64) projection wastes 16× the work if it is padded to a
-128-row block, while a prefill-sized panel wants the full MXU tile.  This
-module keys measured block choices on ``(m, n, k, dtype, backend)`` and
+The right (bm, bn, bk) depends on the matmul shape, operand dtype, and
+backend — a decode-time (4, 64) projection wastes 16× the work if it is
+padded to a 128-row block, while a prefill-sized panel wants the full MXU
+tile.  This module keys measured block choices on ``(m, n, k, dtype,
+backend)``, the dtype being the one the operands enter the kernel in
+(``…:bfloat16:pallas`` for bf16 serving on the chip), and
 persists them to a JSON cache (``experiments/autotune/ft_matmul.json`` by
 default, override dir with ``REPRO_AUTOTUNE_DIR``) that
 ``build_ftcontext(fused_block="auto")`` loads once per process; unseen
@@ -20,7 +22,7 @@ Cache file format (one object, one entry per shape key)::
 Re-tune on new hardware by deleting stale entries (or pointing
 ``REPRO_AUTOTUNE_DIR`` at a fresh dir) and running::
 
-    python -m repro.kernels.autotune M N K [--backend pallas]
+    python -m repro.kernels.autotune M N K [--dtype bfloat16] [--backend pallas]
 
 or passing ``autotune_shapes=[(m, n, k), ...]`` to ``build_ftcontext`` on a
 TPU host (docs/kernels.md).  Measurements are min-of-repeats wall time of
@@ -40,8 +42,9 @@ import jax.numpy as jnp
 import numpy as np
 
 # Candidate grid for the measured search: MXU-aligned tiles plus small-M
-# blocks for decode shapes.  bn/bk stay 128-multiples (f32 lane tiling);
-# bm may shrink to 8 (sublane tile) for skinny activations.
+# blocks for decode shapes.  bn/bk stay 128-multiples (lane tiling); bm may
+# shrink to the operand dtype's sublane tile (8 in f32, 16 in bf16) for
+# skinny activations — smaller candidates are skipped on the chip.
 DEFAULT_CANDIDATES: tuple[tuple[int, int, int], ...] = (
     (8, 128, 128),
     (16, 128, 128),
@@ -116,20 +119,29 @@ def reset_cache() -> None:
     _CACHE, _CACHE_PATH = None, None
 
 
-def default_block(m: int, n: int, k: int, *, backend: str = "pallas") -> tuple[int, int, int]:
+def sublane_tile(dtype) -> int:
+    """Rows of one TPU sublane tile for operands of ``dtype``: 8 for 4-byte
+    values, 16 for 2-byte ones (two packed to a 32-bit sublane word)."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def default_block(m: int, n: int, k: int, *, backend: str = "pallas",
+                  dtype=jnp.float32) -> tuple[int, int, int]:
     """Shape-aware heuristic for shapes the cache has not seen: full MXU
-    tiles, except bm shrinks (in sublane-multiple steps) for skinny
-    activations so a (4, N) decode row is padded to 8 rows, not 128."""
+    tiles, except bm shrinks (in sublane-tile steps of the operand dtype)
+    for skinny activations, so a (4, N) decode row is padded to 8 rows in
+    f32 or 16 in bf16, not 128."""
     del backend  # same heuristic everywhere the kernel runs
-    return (min(128, _round_up(max(m, 1), 8)), 128, 128)
+    return (min(128, _round_up(max(m, 1), sublane_tile(dtype))), 128, 128)
 
 
-def validate_fused_block(block, *, backend: str) -> tuple[int, int, int]:
+def validate_fused_block(block, *, backend: str, dtype=jnp.float32) -> tuple[int, int, int]:
     """Validate an explicit ``fused_block`` against backend tile constraints
-    at context build — a clear error here instead of a Pallas lowering
-    failure at first trace.  Non-divisible *input shapes* are fine (the
-    dispatch zero-pads to block multiples); the block itself must be
-    positive and, for the compiled TPU kernel, (8, 128, 128)-aligned."""
+    — a clear error instead of a Pallas lowering failure.  Non-divisible
+    *input shapes* are fine (the dispatch zero-pads to block multiples); the
+    block itself must be positive and, for the compiled TPU kernel, aligned
+    to the operand dtype's (sublane, 128, 128) tile.  Context build checks
+    the f32 tile; each fused call checks again with its operand dtype."""
     if (not isinstance(block, (tuple, list)) or len(block) != 3
             or not all(isinstance(b, int) and not isinstance(b, bool) and b > 0 for b in block)):
         raise ValueError(
@@ -137,12 +149,13 @@ def validate_fused_block(block, *, backend: str) -> tuple[int, int, int]:
             f"ints, got {block!r}"
         )
     bm, bn, bk = (int(b) for b in block)
-    if backend == "pallas" and (bm % 8 or bn % 128 or bk % 128):
+    sub = sublane_tile(dtype)
+    if backend == "pallas" and (bm % sub or bn % 128 or bk % 128):
         raise ValueError(
             f"fused_block {(bm, bn, bk)} violates the TPU tile constraints: "
-            f"bm must be a multiple of 8 and bn/bk multiples of 128 "
-            f"(f32 sublane×lane tiling); pick an aligned block or use "
-            f"fused_block='auto'"
+            f"bm must be a multiple of {sub} and bn/bk multiples of 128 "
+            f"({jnp.dtype(dtype).name} sublane×lane tiling); pick an aligned "
+            f"block or use fused_block='auto'"
         )
     return (bm, bn, bk)
 
@@ -150,12 +163,12 @@ def validate_fused_block(block, *, backend: str) -> tuple[int, int, int]:
 def resolve_block(m: int, n: int, k: int, *, dtype=jnp.float32,
                   backend: str = "pallas") -> tuple[int, int, int]:
     """The ``fused_block="auto"`` lookup: persisted cache hit, else the
-    heuristic.  Called at trace time with static shapes — the result is a
-    compile-time constant."""
+    heuristic, both keyed on the operand dtype.  Called at trace time with
+    static shapes — the result is a compile-time constant."""
     entry = load_cache().get(_key(m, n, k, dtype, backend))
     if entry is not None:
         return tuple(entry["block"])
-    return default_block(m, n, k, backend=backend)
+    return default_block(m, n, k, backend=backend, dtype=dtype)
 
 
 def _time_block(m: int, n: int, k: int, dtype, block: tuple[int, int, int],
@@ -200,9 +213,12 @@ def autotune_block(
     if backend is None:
         backend = "pallas" if jax.default_backend() == "tpu" else "interpret"
     interpret = backend != "pallas"
+    sub = sublane_tile(dtype)
     best_blk, best_ms = None, float("inf")
     for cand in candidates:
-        blk = validate_fused_block(cand, backend=backend)
+        if backend == "pallas" and cand[0] % sub:
+            continue  # bm below the dtype's sublane tile: Mosaic refuses it
+        blk = validate_fused_block(cand, backend=backend, dtype=dtype)
         ms = _time_block(m, n, k, dtype, blk, interpret=interpret,
                          rows=rows, cols=cols, repeats=repeats, steps=steps)
         if ms < best_ms:

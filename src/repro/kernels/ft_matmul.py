@@ -38,6 +38,16 @@ constant index map); otherwise the caller passes the full padded ``(m, n)``
 mask and each cell reads its own block.  Either way the prune lands in the
 drain — no post-kernel gather/overwrite pass over the output.
 
+Operands enter in the dtype the caller serves (bf16 or f32, ``x`` and ``w``
+alike): each tile is multiplied as it arrives into an f32 VMEM accumulator
+(``preferred_element_type=jnp.float32``), and the drain and the output are
+f32.  A bf16 product is exact in f32, so on the chip bf16 operands give
+bit-for-bit the output of the same values up-cast — and the fault masks act
+on the same f32 accumulator bits — without an f32 copy of any weight
+(interpret mode runs XLA:CPU's dot, whose bf16 form may sum a row in
+another order than its f32 form).  On the chip ``bm`` is a multiple of the
+operand dtype's sublane tile (8 in f32, 16 in bf16).
+
 Two grid layouts share the drain epilogue:
 
   * :func:`ft_matmul` — 2-D ``(M, K) @ (K, N)``; leading dims of N-D inputs
@@ -102,11 +112,7 @@ def _kernel(meta_ref, x_ref, w_ref, pmask_ref, o_ref, acc_ref, *, rows, cols):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        x_ref[...].astype(jnp.float32),
-        w_ref[...].astype(jnp.float32),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += jnp.dot(x_ref[...], w_ref[...], preferred_element_type=jnp.float32)
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _drain():
@@ -171,11 +177,7 @@ def _kernel_batched(meta_ref, x_ref, w_ref, pmask_ref, o_ref, acc_ref, *, rows, 
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        x_ref[0].astype(jnp.float32),
-        w_ref[0].astype(jnp.float32),
-        preferred_element_type=jnp.float32,
-    )
+    acc_ref[...] += jnp.dot(x_ref[0], w_ref[0], preferred_element_type=jnp.float32)
 
     @pl.when(k == pl.num_programs(3) - 1)
     def _drain():

@@ -63,6 +63,7 @@ class SiteCall:
     count: int          # static calls per step with this (site, shape)
     dispatch: str       # resolved dispatch: plain | twopass | fused
     protected: bool     # routed through the fault-aware engine path
+    operand_dtype: str  # common dtype the operands are multiplied in
 
 
 @jax.tree_util.register_pytree_node_class
@@ -160,11 +161,11 @@ def trace_site_calls(fn: Callable, ftc, *args, **kwargs) -> tuple[SiteCall, ...]
 
     Abstractly traces ``fn`` (``jax.eval_shape`` — shapes only, no compute)
     with the context's record hook armed; every ``ftc.matmul``/``einsum``
-    call appends a (site, shape, dispatch) row scaled by the product of
-    enclosing ``lax.scan`` lengths (the layer stacks trace their body once
-    but execute it per layer).  Identical rows are merged with summed
-    counts, so a 24-layer stack contributes one ledger entry per distinct
-    (site, shape), not 24.
+    call appends a (site, shape, dispatch, operand dtype) row scaled by the
+    product of enclosing ``lax.scan`` lengths (the layer stacks trace their
+    body once but execute it per layer).  Identical rows are merged with
+    summed counts, so a 24-layer stack contributes one ledger entry per
+    distinct (site, shape), not 24.
 
     ``args``/``kwargs`` may be concrete arrays or ShapeDtypeStructs; models
     that branch on ``cfg.unroll`` record correctly either way (unrolled
@@ -172,11 +173,11 @@ def trace_site_calls(fn: Callable, ftc, *args, **kwargs) -> tuple[SiteCall, ...]
     """
     raw: list[SiteCall] = []
 
-    def record(*, site, m, n, count, dispatch, protected):
+    def record(*, site, m, n, count, dispatch, protected, operand_dtype):
         mult = int(count)
         for k in _SCAN_STACK:
             mult *= k
-        raw.append(SiteCall(site, int(m), int(n), mult, dispatch, protected))
+        raw.append(SiteCall(site, int(m), int(n), mult, dispatch, protected, operand_dtype))
 
     prev = ftc._obs_record
     ftc._obs_record = record
@@ -188,10 +189,11 @@ def trace_site_calls(fn: Callable, ftc, *args, **kwargs) -> tuple[SiteCall, ...]
 
     merged: dict[tuple, int] = {}
     for c in raw:
-        key = (c.site, c.m, c.n, c.dispatch, c.protected)
+        key = (c.site, c.m, c.n, c.dispatch, c.protected, c.operand_dtype)
         merged[key] = merged.get(key, 0) + c.count
     return tuple(
-        SiteCall(site=k[0], m=k[1], n=k[2], count=v, dispatch=k[3], protected=k[4])
+        SiteCall(site=k[0], m=k[1], n=k[2], count=v, dispatch=k[3], protected=k[4],
+                 operand_dtype=k[5])
         for k, v in sorted(merged.items(), key=lambda kv: kv[0])
     )
 

@@ -7,6 +7,9 @@ program holds the Pallas kernel (``tpu_custom_call``):
 
   * ``ft_matmul`` at qwen1.5-0.5b's decode and prefill projection shapes,
     with no prune mask, a full ``(m, n)`` mask and a periodic tile;
+  * ``ft_matmul`` fed bf16 operands at starcoder2-3b's decode projection
+    shapes, on 64 slots directly and on 4 slots through the fused
+    ``FTContext`` dispatch (block from the bf16 heuristic, rows padded);
   * ``ft_matmul_batched`` at deepseek-moe-16b's expert shapes (64 experts,
     d_model 2048, d_expert 1408);
   * ``probe_check`` at the server's probe shapes (8x8 and 32x32 arrays) and
@@ -16,6 +19,7 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and the test workers all import this
 file.
 """
+import dataclasses
 import os
 
 import jax
@@ -23,10 +27,17 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.hyca_dla import dla_config
+from repro.core.engine import empty_fault_state
+from repro.core.ftcontext import build_ftcontext
 from repro.kernels.dppu_recompute import probe_check
 from repro.kernels.ft_matmul import ft_matmul, ft_matmul_batched
 
 ROWS = COLS = 32
+
+# starcoder2-3b decode projections (k, n): q and out, k and v, up, down, the
+# tied head
+STARCODER2_DECODE = [(3072, 3072), (3072, 256), (3072, 12288), (12288, 3072), (3072, 49152)]
 
 
 @pytest.fixture(scope="module")
@@ -71,6 +82,31 @@ def test_ft_matmul_compiles(one_chip, m, k, n, block, mask):
     if mask_shape is not None:
         args.append(_sds(mask_shape, jnp.int32, one_chip))
     _assert_kernel(lambda *a: ft_matmul(*a, bm=bm, bn=bn, bk=bk), *args)
+
+
+@pytest.mark.parametrize("k,n", STARCODER2_DECODE)
+def test_ft_matmul_bf16_compiles(one_chip, k, n):
+    """bf16 operands straight into the kernel, 64 decode slots in one block."""
+    _assert_kernel(
+        lambda x, w, meta: ft_matmul(x, w, meta, bm=64, bn=128, bk=128),
+        _sds((64, k), jnp.bfloat16, one_chip), _sds((k, n), jnp.bfloat16, one_chip),
+        _sds((ROWS, COLS), jnp.int32, one_chip),
+    )
+
+
+@pytest.mark.parametrize("k,n", STARCODER2_DECODE)
+def test_fused_dispatch_bf16_compiles_at_4_slots(one_chip, k, n):
+    """A 4-slot bf16 decode through ``FTContext``: the block follows the
+    bf16 sublane tile and the rows are padded up to it."""
+    hyca = dla_config()
+    ctx = dataclasses.replace(
+        build_ftcontext(empty_fault_state(hyca.rows * hyca.cols), hyca, dispatch="fused"),
+        fused_backend="pallas",
+    )
+    _assert_kernel(
+        lambda x, w: ctx.matmul(x, w, site="ffn"),
+        _sds((4, k), jnp.bfloat16, one_chip), _sds((k, n), jnp.bfloat16, one_chip),
+    )
 
 
 @pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)])

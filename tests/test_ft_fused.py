@@ -22,6 +22,7 @@
     decode step of ALL TEN registry configs records ZERO fallbacks.
 """
 import dataclasses
+import functools
 import json
 import warnings
 
@@ -30,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import ARCH_IDS, get_smoke_config
+from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.core.engine import (
     FaultState,
     HyCAConfig,
@@ -439,3 +440,166 @@ def test_zero_fallbacks_across_all_registry_configs():
     assert site_fallback_total() == {}, (
         f"silent twopass fallbacks under dispatch='fused': {site_fallback_total()}"
     )
+
+
+# --------------------------------------------------------------------------- #
+# operand dtype: bf16 operands enter the kernel as bf16, accumulate in f32
+# --------------------------------------------------------------------------- #
+def _meta_and_mask(case: str, bm: int, bn: int):
+    """Packed meta grid and prune mask for one fault case: ``unrepaired``
+    (faults on an unprotected array: the stuck-at mux engages), ``repaired``
+    (every fault within DPPU capacity) or ``pruned`` (over capacity under a
+    remap + prune RepairPlan)."""
+    from repro.core.engine import META_PRUNE_SHIFT, fault_meta_grid
+
+    mode, n_faults, plan = {
+        "unrepaired": ("unprotected", 12, None),
+        "repaired": ("protected", 4, None),
+        "pruned": ("protected", 12, _plan(3)),
+    }[case]
+    meta = fault_meta_grid(_state(n_faults, seed=n_faults), _hyca(mode), plan)
+    if plan is None:
+        return meta, None
+    keep = jnp.where((meta >> META_PRUNE_SHIFT) & 1 > 0, jnp.int32(0), jnp.int32(-1))
+    return meta, jnp.tile(keep, (bm // ROWS, bn // COLS))
+
+
+@pytest.mark.parametrize("case", ["unrepaired", "repaired", "pruned"])
+@pytest.mark.parametrize("kernel", ["ft_matmul", "ft_matmul_batched"])
+def test_kernel_bf16_operands_equal_f32_upcast(rng, kernel, case):
+    """bf16 operands give output bit-equal to the same values up-cast to
+    f32: the products are exact in the f32 accumulator, and the stuck-at
+    mux and the prune mask act on the same accumulator bits.
+
+    The operands are small integers, so every partial sum is exact too:
+    under interpret mode the kernel's dot is XLA:CPU's, whose bf16 dot sums
+    a row in another order than its f32 dot, and random operands would
+    differ by that reassociation alone (an ulp or so), not by anything the
+    kernel does with the operand dtype."""
+    from repro.kernels import ft_matmul as fm
+
+    bm, bn, bk = 16, 128, 128
+    meta, pmask = _meta_and_mask(case, bm, bn)
+    lead = (2,) if kernel == "ft_matmul_batched" else ()
+    # 8 x 8 output tiles: every PE of the 8 x 8 array owns one
+    x = jnp.asarray(rng.integers(-8, 9, lead + (8 * bm, 256)), jnp.bfloat16)
+    w = jnp.asarray(rng.integers(-8, 9, lead + (256, 8 * bn)), jnp.bfloat16)
+    run = functools.partial(getattr(fm, kernel), bm=bm, bn=bn, bk=bk, interpret=True)
+    got = run(x, w, meta, pmask)
+    want = run(x.astype(jnp.float32), w.astype(jnp.float32), meta, pmask)
+    assert got.dtype == jnp.float32
+    assert _bits_equal(got, want)
+    clean = run(x, w, jnp.zeros_like(meta), None)
+    n_moved = int((np.asarray(got) != np.asarray(clean)).sum())
+    if case == "repaired":
+        assert n_moved == 0
+    else:
+        assert n_moved > 0, "the fault epilogue did not engage"
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of a closed jaxpr and of the jaxprs it calls, without
+    descending into a ``pallas_call``'s kernel body."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk_eqns(sub)
+
+
+@pytest.mark.parametrize("op", ["matmul", "einsum"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_fused_hands_operands_over_in_their_dtype(rng, op, dtype):
+    """In the jaxpr of the interpret-backend fused dispatch, the kernel
+    receives the operands in their own dtype, and no weight-sized array is
+    converted to f32 on the way; the call ledger names that dtype."""
+    from repro.obs.counters import trace_site_calls
+
+    ctx = _interpret_ctx(_state(4, seed=3), _hyca("protected"), block=(16, 128, 128))
+    if op == "matmul":
+        x = jnp.asarray(rng.standard_normal((4, 256)), dtype)
+        w = jnp.asarray(rng.standard_normal((256, 384)), dtype)
+        fn = lambda c, x, w: c.matmul(x, w, site="ffn")  # noqa: E731
+    else:
+        x = jnp.asarray(rng.standard_normal((1, 2, 4, 256)), dtype)
+        w = jnp.asarray(rng.standard_normal((2, 256, 384)), dtype)
+        fn = lambda c, x, w: c.einsum(EINSUM_SPECS[0], x, w, site="moe.expert")  # noqa: E731
+    jaxpr = jax.make_jaxpr(functools.partial(fn, ctx))(x, w)
+    eqns = list(_walk_eqns(jaxpr.jaxpr))
+    kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert len(kernels) == 1
+    # operands: scalar-prefetch meta, x, w, prune mask
+    assert [v.aval.dtype for v in kernels[0].invars[1:3]] == [jnp.dtype(dtype)] * 2
+    upcasts = [
+        e for e in eqns
+        if e.primitive.name == "convert_element_type"
+        and e.params["new_dtype"] == jnp.float32
+        and e.invars[0].aval.dtype != jnp.float32
+        and e.outvars[0].aval.size >= w.size
+    ]
+    assert upcasts == []
+    ledger = trace_site_calls(fn, ctx, x, w)
+    assert [c.operand_dtype for c in ledger] == [jnp.dtype(dtype).name]
+
+
+def test_starcoder2_decode_step_feeds_every_protected_call_bf16():
+    """The batch benchmark's decode step (starcoder2-3b, first 15 layers, 64
+    slots): every protected call of the step multiplies bf16 operands."""
+    from repro.obs.counters import trace_site_calls
+
+    cfg = dataclasses.replace(get_config("starcoder2-3b"), n_layers=15)
+    ftc = build_ftcontext(_state(4, seed=41), _hyca("protected"), dispatch="fused")
+    params = jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
+    cache = jax.eval_shape(lambda: init_cache(cfg, 64, 16))
+    tok = jax.ShapeDtypeStruct((64, 1), jnp.int32)
+    ledger = trace_site_calls(
+        lambda c, p, ch, t: decode_step(p, cfg, ch, {"token": t}, ftc=c), ftc, params, cache, tok)
+    assert all(c.protected for c in ledger)
+    # q, k, v, out, up, down per layer, and the tied head
+    assert sum(c.count for c in ledger) == 6 * 15 + 1
+    assert {c.operand_dtype for c in ledger} == {"bfloat16"}
+
+
+@pytest.mark.parametrize("dtype,m,want_bm", [
+    (jnp.float32, 4, 8), (jnp.bfloat16, 4, 16), (jnp.bfloat16, 17, 32),
+    (jnp.bfloat16, 64, 64), (jnp.float32, 100, 104), (jnp.bfloat16, 100, 112),
+])
+def test_default_block_follows_operand_sublane_tile(dtype, m, want_bm):
+    assert autotune.default_block(m, 512, 64, dtype=dtype) == (want_bm, 128, 128)
+    # a cache miss resolves to the same heuristic on every backend
+    for backend in ("pallas", "interpret"):
+        assert autotune.resolve_block(m, 512, 64, dtype=dtype, backend=backend) == (want_bm, 128, 128)
+
+
+def test_resolve_block_keys_on_operand_dtype(tmp_path, monkeypatch):
+    monkeypatch.setenv("REPRO_AUTOTUNE_DIR", str(tmp_path))
+    autotune.reset_cache()
+    autotune.save_cache({"64x3072x3072:bfloat16:pallas": {"block": [64, 256, 128], "ms": 1.0}})
+    autotune.reset_cache()
+    assert autotune.resolve_block(64, 3072, 3072, dtype=jnp.bfloat16, backend="pallas") == (64, 256, 128)
+    # the f32 key of the same shape misses to the heuristic
+    assert autotune.resolve_block(64, 3072, 3072, dtype=jnp.float32, backend="pallas") == (64, 128, 128)
+
+
+def test_pallas_bf16_block_needs_16_row_sublane_tile(rng):
+    """bm 8 fits the f32 tile but not bf16's: on the pallas backend it is
+    refused for bf16 operands when the call is dispatched, with the same
+    error as validate_fused_block; f32 operands keep it."""
+    with pytest.raises(ValueError, match="tile constraints"):
+        autotune.validate_fused_block((8, 128, 128), backend="pallas", dtype=jnp.bfloat16)
+    assert autotune.validate_fused_block((16, 128, 128), backend="pallas",
+                                         dtype=jnp.bfloat16) == (16, 128, 128)
+    assert autotune.validate_fused_block((8, 128, 128), backend="pallas") == (8, 128, 128)
+    assert autotune.validate_fused_block((8, 128, 128), backend="interpret",
+                                         dtype=jnp.bfloat16) == (8, 128, 128)
+    ctx = dataclasses.replace(
+        build_ftcontext(_state(2, seed=0), _hyca("protected"), dispatch="fused",
+                        fused_block=(8, 128, 128)),
+        fused_backend="pallas",
+    )
+    x = jax.ShapeDtypeStruct((4, 256), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((256, 128), jnp.bfloat16)
+    with pytest.raises(ValueError, match="tile constraints"):
+        jax.eval_shape(lambda x, w: ctx.matmul(x, w, site="ffn"), x, w)
+
