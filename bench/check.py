@@ -2,7 +2,8 @@
 
 After the window, a sample of the requests the server finished, drawn from
 the seed and always holding the longest of them, is run through the plain
-float32 reference (``bench.reference``): each prompt followed by the tokens
+float32 reference of the configuration's model family
+(``bench.families``, ``bench/reference/``): each prompt followed by the tokens
 the server gave out for it.  At every position that produced a served token
 the reference gives its logits; the number compared is the widest gap by
 which a served token's reference logit lies below the reference's best
@@ -18,7 +19,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from bench.reference.dense import DenseSpec, logits_at
+from bench import families
 
 
 def sample_finished(reqs, seed: int, n: int) -> list:
@@ -57,16 +58,20 @@ def _gap_of(logits, best, pick, mask) -> float:
     return float(np.asarray(best - at)[mask].max())
 
 
-def served_gaps(config: dict, seed: int, sample, smax: int, *, control: bool = False) -> dict:
-    """``{"max_gap": ..., "served_tokens": ...}`` of the sample, and with
-    ``control`` the float8 reference's ``control_gap`` at the same positions."""
-    spec = DenseSpec.from_config(config)
+def served_gaps(config: dict, seed: int, sample, smax: int, *, control: bool = False,
+                family=None) -> dict:
+    """``{"max_gap": ..., "served_tokens": ...}`` of the sample against the
+    reference of the configuration's family (``bench.families``, looked up
+    by the file's ``family`` key where none is given), and with ``control``
+    the float8 reference's ``control_gap`` at the same positions."""
+    family = family or families.load(config)
+    spec = family.reference_spec(config)
     tokens, pos, served, mask = _arrays(sample, smax)
-    logits = logits_at(spec, seed, tokens, pos)
+    logits = family.logits_at(spec, seed, tokens, pos)
     best = logits.max(-1)
     out = {"max_gap": _gap_of(logits, best, served, mask), "served_tokens": int(mask.sum())}
     if control:
-        pick = np.asarray(logits_at(spec, seed, tokens, pos, mode="fp8").argmax(-1))
+        pick = np.asarray(family.logits_at(spec, seed, tokens, pos, mode="fp8").argmax(-1))
         out["control_gap"] = _gap_of(logits, best, pick, mask)
     return out
 

@@ -33,7 +33,8 @@ def readings(cell, seed: int, seconds: float) -> dict:
     del server
     harness.free_device_memory()
     sample = check.sample_finished(rec.reqs.values(), seed, int(cell.traffic["check_requests"]))
-    return check.served_gaps(cell.config, seed, sample, int(cell.config["smax"]), control=True)
+    return check.served_gaps(cell.config, seed, sample, int(cell.config["smax"]), control=True,
+                             family=cell.family)
 
 
 def main(argv=None) -> int:

@@ -26,10 +26,12 @@ import json
 import math
 import pathlib
 import time
+import types
 from typing import Callable
 
 import numpy as np
 
+from bench import families
 from bench.arrivals import Schedule
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -49,6 +51,11 @@ class Cell:
     chips: int = 1
     end_to_end: list = dataclasses.field(default_factory=list)
     per_layer: list = dataclasses.field(default_factory=list)
+    family: types.ModuleType | None = None    # bench.families; by the config's name if None
+
+    def __post_init__(self):
+        if self.family is None:
+            self.family = families.load(self.config)
 
 
 def _reports(metric: dict, workload: str) -> bool:
@@ -57,44 +64,35 @@ def _reports(metric: dict, workload: str) -> bool:
 
 def load_cell(workload: str, root: pathlib.Path = ROOT) -> Cell:
     """The cell named ``workload``: its configuration, traffic mix, limits of
-    the correctness comparison and the metrics it reports, each found by name."""
+    the correctness comparison, the metrics it reports and the configuration's
+    model family, each found by name."""
     bench = json.loads((root / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
         raise KeyError(f"unknown workload {workload!r}; known: {sorted(cells)}")
     w = cells[workload]
     config_file = {c["name"]: c["file"] for c in bench["configs"]}[w["config"]]
+    config = json.loads((root / config_file).read_text())
     e2e = [m for m in bench["end_to_end"] if _reports(m, workload)]
     moved = {m["name"] for m in e2e}
     per_layer = [m for m in bench["per_layer"]
                  if (workload in m["workloads"] if "workloads" in m else m["moves"] in moved)]
     return Cell(
         name=workload,
-        config=json.loads((root / config_file).read_text()),
+        config=config,
         traffic=json.loads((root / "bench" / "traffic" / f"{w['traffic']}.json").read_text()),
         limits=json.loads((root / "bench" / "limits" / f"{workload}.json").read_text()),
         chips=int(w["chips"]),
         end_to_end=e2e,
         per_layer=per_layer,
+        family=families.load(config, root / "bench" / "families"),
     )
 
 
-def lm_config(config: dict):
-    """The program's ``LMConfig`` for a configuration file: the registry's
-    entry for its architecture with every size taken from the file."""
-    from repro.configs import get_config
-
-    act = {"silu": "silu", "gelu_pytorch_tanh": "gelu"}[config["hidden_act"]]
-    return dataclasses.replace(
-        get_config(config["arch"]),
-        n_layers=config["num_hidden_layers"], d_model=config["hidden_size"],
-        n_heads=config["num_attention_heads"], n_kv=config["num_key_value_heads"],
-        d_ff=config["intermediate_size"], vocab=config["vocab_size"],
-        head_dim=None, rope_theta=float(config["rope_theta"]),
-        norm="ln" if "norm_epsilon" in config else "rms",
-        gated_ffn=act == "silu", act=act,
-        tie_embeddings=bool(config["tie_word_embeddings"]),
-    )
+def lm_config(config: dict, family: types.ModuleType | None = None):
+    """The program's ``LMConfig`` for a configuration file, by its family
+    (looked up by the file's ``family`` key where none is given)."""
+    return (family or families.load(config)).lm_config(config)
 
 
 def build_server(cell: Cell, seed: int):
@@ -110,7 +108,7 @@ def build_server(cell: Cell, seed: int):
         dppu_size=prot["dppu_size"], dispatch=prot["dispatch"],
         scan_block=prot["scan_block"], fault_rate=prot["fault_rate"], seed=seed,
     )
-    bundle = ModelBundle(cfg, lm=lm_config(cell.config))
+    bundle = ModelBundle(cfg, lm=cell.family.lm_config(cell.config))
     server = FaultTolerantServer(cfg, bundle=bundle)
     if prot["faults_at_boot"]:
         server.injector.inject_n(int(prot["faults_at_boot"]))
@@ -458,7 +456,8 @@ def per_layer(cell: Cell, rec: Record, trace_path: str, peaks) -> tuple[dict, di
         raise RuntimeError("the trace holds no complete bench.step span")
     n_steps = len(wins[0].spans_named("step"))
     ctx = layer_metrics.Context(
-        window=wins[0], config=cell.config, peaks=peaks, step_load=rec.step_load[rec.trace_first_step:][:n_steps],
+        window=wins[0], config=cell.config, peaks=peaks,
+        step_load=rec.step_load[rec.trace_first_step:][:n_steps], family=cell.family,
     )
     metrics = {}
     for m in cell.per_layer:
@@ -490,8 +489,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *, t_start: flo
         del server
         free_device_memory()
         sample = check.sample_finished(rec.reqs.values(), seed, int(cell.traffic["check_requests"]))
-        readings = (check.served_gaps(cell.config, seed, sample, int(cell.config["smax"]))
-                    if sample else None)
+        readings = (check.served_gaps(cell.config, seed, sample, int(cell.config["smax"]),
+                                      family=cell.family) if sample else None)
         correct, checks = check.judge(readings, cell.limits)
         due = due_in_window(rec)
         if cell.traffic["loop"] == "open":

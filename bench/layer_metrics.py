@@ -17,10 +17,10 @@ change with it; a reader that no longer finds them goes silent):
 from __future__ import annotations
 
 import dataclasses
-import importlib.util
 import pathlib
+import types
 
-from bench import trace_reduce, work
+from bench import families, trace_reduce
 
 STEP_MODULE = r"^jit__step\("
 KERNEL = r"^%ft_matmul(\.\d+)? = .*tpu_custom_call"
@@ -33,6 +33,11 @@ class Context:
     config: dict                  # the cell's configuration file
     peaks: object                 # bench.peaks.ChipPeaks of the chip
     step_load: list               # (active slots, attended positions) per traced step
+    family: types.ModuleType | None = None   # bench.families; by the config's name if None
+
+    def __post_init__(self):
+        if self.family is None:
+            self.family = families.load(self.config)
 
 
 def _mean(xs) -> float | None:
@@ -71,7 +76,7 @@ def decode_mfu(ctx: Context) -> float | None:
     w = ctx.window
     if not ctx.step_load or w.seconds <= 0:
         return None
-    flops = sum(work.step_model_flops(ctx.config, a, c) for a, c in ctx.step_load)
+    flops = sum(ctx.family.step_model_flops(ctx.config, a, c) for a, c in ctx.step_load)
     return 100.0 * flops / w.seconds / ctx.peaks.bf16_flops
 
 
@@ -82,7 +87,7 @@ def ft_matmul_roofline(ctx: Context) -> float | None:
     w = ctx.window
     steps = w.modules_matching(STEP_MODULE)
     kernels = w.ops_within(steps, KERNEL)
-    calls = work.decode_calls(ctx.config, int(ctx.config["n_slots"]))
+    calls = ctx.family.decode_calls(ctx.config, int(ctx.config["n_slots"]))
     if not steps or len(kernels) != len(steps) * sum(c.count for c in calls):
         return None
     least = len(steps) * sum(c.least_s(ctx.peaks) for c in calls)
@@ -91,8 +96,4 @@ def ft_matmul_roofline(ctx: Context) -> float | None:
 
 def read(name: str, ctx: Context, metrics_dir: pathlib.Path = METRICS_DIR) -> float | None:
     """Run the reader file of metric ``name``."""
-    path = metrics_dir / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    return families.load_file(metrics_dir / f"{name}.py").read(ctx)

@@ -106,10 +106,10 @@ def test_backlog_schedule_has_no_arrival_times():
 
 
 def _mini_root(tmp_path: pathlib.Path) -> pathlib.Path:
-    """A checkout holding the repository's own BENCHMARK.json and bench
-    data files, to which a test adds new files."""
+    """A checkout holding the repository's own BENCHMARK.json, bench data
+    files and model families, to which a test adds new files."""
     (tmp_path / "bench").mkdir()
-    for sub in ("configs", "traffic", "limits", "metrics"):
+    for sub in ("configs", "traffic", "limits", "metrics", "families"):
         (tmp_path / "bench" / sub).mkdir()
         for f in (ROOT / "bench" / sub).glob("*"):
             if f.is_file():

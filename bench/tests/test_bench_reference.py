@@ -12,22 +12,9 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 from bench.reference.dense import DenseSpec, layer_weights, logits_at  # noqa: E402
+from bench.tests.small import small_config  # noqa: E402
 
 SEED = 2**31 + 17          # larger than a signed 32-bit seed
-
-
-def small_config(arch: str) -> dict:
-    """A configuration file's keys at a size the CPU runs in seconds."""
-    config = {"arch": arch, "hidden_size": 64, "intermediate_size": 128,
-              "num_attention_heads": 4, "num_hidden_layers": 2, "vocab_size": 500,
-              "tie_word_embeddings": True, "n_slots": 4, "smax": 48}
-    if arch == "qwen1.5-0.5b":
-        config.update(hidden_act="silu", num_key_value_heads=4, rms_norm_eps=1e-6,
-                      rope_theta=1e6)
-    else:
-        config.update(hidden_act="gelu_pytorch_tanh", num_key_value_heads=2,
-                      norm_epsilon=1e-5, rope_theta=999999.4420358813)
-    return config
 
 
 ARCHS = ["qwen1.5-0.5b", "starcoder2-3b"]

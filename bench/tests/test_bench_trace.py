@@ -102,7 +102,7 @@ def test_roofline_silent_unless_one_kernel_per_model_call(monkeypatch):
     assert layer_metrics.ft_matmul_roofline(ctx) is None   # 2 kernels a step, 8 calls
     calls = [work.Call("attn.out", 64, 1024, 1024, 2)]
     least = 2 * sum(c.least_s(PEAKS["TPU v5 lite"]) for c in calls)
-    monkeypatch.setattr(work, "decode_calls", lambda config, n: calls)
+    monkeypatch.setattr(ctx.family, "decode_calls", lambda config, n: calls)
     assert layer_metrics.ft_matmul_roofline(ctx) == pytest.approx(100 * least / (5 * MS))
 
 
