@@ -13,6 +13,7 @@ _MODULES = {
     "starcoder2-3b": "repro.configs.starcoder2_3b",
     "granite-8b": "repro.configs.granite_8b",
     "deepseek-moe-16b": "repro.configs.deepseek_moe_16b",
+    "deepseek-v2-lite": "repro.configs.deepseek_v2_lite",
     "granite-moe-3b-a800m": "repro.configs.granite_moe_3b",
     "rwkv6-7b": "repro.configs.rwkv6_7b",
     "llava-next-mistral-7b": "repro.configs.llava_next_mistral_7b",

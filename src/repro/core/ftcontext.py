@@ -23,7 +23,7 @@ Models receive an optional ``ftc`` and route every weight matmul through
 matmuls).  ``ftc=None`` is the production fast path: plain matmuls, no fault
 machinery anywhere in the lowered HLO.
 
-Bit-exactness invariant (property-tested across all ten registry configs):
+Bit-exactness invariant (property-tested across every registry config):
 with ``mode="protected"`` and #faults ≤ DPPU capacity, every dispatch mode
 produces outputs bit-exact with ``mode="off"``.
 """
@@ -440,6 +440,10 @@ class FTContext:
             # gather cover every expert.  Replaces the vmapped two-pass
             # engine (corrupt + overwrite + prune per expert).
             pref = jnp.int32 if jnp.issubdtype(x.dtype, jnp.integer) else jnp.float32
+            if pref == jnp.float32:
+                # XLA:CPU has no batched bf16 x bf16 -> f32 dot ("DotThunk");
+                # a bf16 product is exact in f32, so f32 operands lose nothing
+                x, w = x.astype(jnp.float32), w.astype(jnp.float32)
             out = jnp.einsum(spec, x, w, preferred_element_type=pref)
             meta = fault_meta_grid(self.state, cfg, plan)
             row_res = (

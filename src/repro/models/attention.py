@@ -14,7 +14,17 @@ import jax.numpy as jnp
 
 from repro.core.ftcontext import site_matmul
 from repro.dist.sharding import shard as _shard
-from repro.models.layers import Params, apply_rope, dense_init, rmsnorm, rmsnorm_init, scan_or_unroll
+from repro.models.layers import (
+    Params,
+    YaRN,
+    apply_rope,
+    dense_init,
+    rmsnorm,
+    rmsnorm_init,
+    scan_or_unroll,
+    yarn_freqs,
+    yarn_mscale,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -151,22 +161,47 @@ def gqa_cache_init(cfg: AttnConfig, batch: int, smax: int, dtype=jnp.bfloat16) -
 class MLAConfig:
     d_model: int
     n_heads: int
-    q_lora: int = 768
+    q_lora: int | None = 768     # None: one query projection, no low-rank path
     kv_lora: int = 256
     d_nope: int = 64
     d_rope: int = 32
     d_v: int = 64
     rope_theta: float = 10000.0
     q_block: int = 512
+    rope_scaling: YaRN | None = None
+
+    @property
+    def softmax_scale(self) -> float:
+        """1/sqrt(d_nope + d_rope), times YaRN's temperature squared."""
+        scale = 1.0 / ((self.d_nope + self.d_rope) ** 0.5)
+        s = self.rope_scaling
+        if s is not None and s.mscale_all_dim:
+            scale *= yarn_mscale(s.factor, s.mscale_all_dim) ** 2
+        return scale
+
+    def rope(self, x, positions):
+        """Rotary embedding of the rope part, with YaRN's frequencies and
+        its cos/sin attenuation where the config scales the rope."""
+        s = self.rope_scaling
+        if s is None:
+            return apply_rope(x, positions, self.rope_theta)
+        x = apply_rope(x, positions, freqs=yarn_freqs(x.shape[-1], self.rope_theta, s))
+        atten = yarn_mscale(s.factor, s.mscale) / yarn_mscale(s.factor, s.mscale_all_dim)
+        return x if atten == 1.0 else (x * atten).astype(x.dtype)
 
 
 def mla_init(key, cfg: MLAConfig) -> Params:
     ks = jax.random.split(key, 6)
     h, dn, dr, dv = cfg.n_heads, cfg.d_nope, cfg.d_rope, cfg.d_v
-    return {
-        "wq_a": dense_init(ks[0], cfg.d_model, cfg.q_lora),
-        "q_norm": rmsnorm_init(cfg.q_lora),
-        "wq_b": dense_init(ks[1], cfg.q_lora, h * (dn + dr)),
+    if cfg.q_lora is None:
+        q = {"wq": dense_init(ks[0], cfg.d_model, h * (dn + dr))}
+    else:
+        q = {
+            "wq_a": dense_init(ks[0], cfg.d_model, cfg.q_lora),
+            "q_norm": rmsnorm_init(cfg.q_lora),
+            "wq_b": dense_init(ks[1], cfg.q_lora, h * (dn + dr)),
+        }
+    return q | {
         "wkv_a": dense_init(ks[2], cfg.d_model, cfg.kv_lora + dr),
         "kv_norm": rmsnorm_init(cfg.kv_lora),
         "wkv_b": dense_init(ks[3], cfg.kv_lora, h * (dn + dv)),
@@ -178,13 +213,16 @@ def _mla_qkr(x, p, cfg: MLAConfig, positions, ftc=None):
     b, s, _ = x.shape
     h, dn, dr = cfg.n_heads, cfg.d_nope, cfg.d_rope
     mm = site_matmul(ftc, "attn.qkv")
-    q = mm(rmsnorm(mm(x, p["wq_a"]), p["q_norm"]), p["wq_b"])
+    if cfg.q_lora is None:
+        q = mm(x, p["wq"])
+    else:
+        q = mm(rmsnorm(mm(x, p["wq_a"]), p["q_norm"]), p["wq_b"])
     q = q.reshape(b, s, h, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = cfg.rope(q_rope, positions)
     kv_a = mm(x, p["wkv_a"])
     c_kv = rmsnorm(kv_a[..., : cfg.kv_lora], p["kv_norm"])  # (B,S,kv_lora)
-    k_rope = apply_rope(kv_a[..., cfg.kv_lora :][:, :, None, :], positions, cfg.rope_theta)[
+    k_rope = cfg.rope(kv_a[..., cfg.kv_lora :][:, :, None, :], positions)[
         :, :, 0
     ]  # (B,S,dr) shared across heads
     return q_nope, q_rope, c_kv, k_rope
@@ -198,7 +236,7 @@ def mla_forward(x, p, cfg: MLAConfig, positions=None, unroll: bool = False, ftc=
     q_nope, q_rope, c_kv, k_rope = _mla_qkr(x, p, cfg, positions, ftc)
     kv = site_matmul(ftc, "attn.qkv")(c_kv, p["wkv_b"]).reshape(b, s, h, dn + dv)
     k_nope, v = kv[..., :dn], kv[..., dn:]
-    scale = 1.0 / ((dn + dr) ** 0.5)
+    scale = cfg.softmax_scale
     qb = min(cfg.q_block, s)
     assert s % qb == 0
     nblk = s // qb
@@ -246,28 +284,30 @@ def mla_decode(x, p, cfg: MLAConfig, cache: Params, ftc=None) -> tuple[jax.Array
     The absorbed latent einsums (w_uk / w_uv contractions) run off the
     protected array: they are reshaped views of ``wkv_b``, which *is*
     protected on the prefill path; coverage here is the q-side projections
-    plus the output projection (see docs/ftcontext.md).
+    plus the output projection (see docs/ftcontext.md).  They, the scores
+    over the latent cache and the softmax run under the device scope
+    ``attn.latent``.
     """
     b = x.shape[0]
     idx = cache["idx"]
-    h, dn, dr, dv = cfg.n_heads, cfg.d_nope, cfg.d_rope, cfg.d_v
+    h, dn, dv = cfg.n_heads, cfg.d_nope, cfg.d_v
     q_nope, q_rope, c_kv_new, k_rope_new = _mla_qkr(x, p, cfg, idx[:, None], ftc)
     bidx = jnp.arange(b)
     c_cache = cache["c_kv"].at[bidx, idx].set(c_kv_new[:, 0].astype(cache["c_kv"].dtype))
     r_cache = cache["k_rope"].at[bidx, idx].set(k_rope_new[:, 0].astype(cache["k_rope"].dtype))
-    w_uk = p["wkv_b"].reshape(cfg.kv_lora, h, dn + dv)[..., :dn]  # (L,H,dn)
-    w_uv = p["wkv_b"].reshape(cfg.kv_lora, h, dn + dv)[..., dn:]  # (L,H,dv)
-    q_abs = jnp.einsum("bhd,lhd->bhl", q_nope[:, 0].astype(jnp.float32), w_uk)
-    scale = 1.0 / ((dn + dr) ** 0.5)
-    sc = (
-        jnp.einsum("bhl,bsl->bhs", q_abs, c_cache.astype(jnp.float32))
-        + jnp.einsum("bhd,bsd->bhs", q_rope[:, 0].astype(jnp.float32), r_cache.astype(jnp.float32))
-    ) * scale
-    smax = c_cache.shape[1]
-    valid = jnp.arange(smax)[None, :] <= idx[:, None]
-    sc = jnp.where(valid[:, None, :], sc, -1e30)
-    wts = jax.nn.softmax(sc, axis=-1)
-    ctx = jnp.einsum("bhs,bsl->bhl", wts, c_cache.astype(jnp.float32))
-    out = jnp.einsum("bhl,lhd->bhd", ctx, w_uv).reshape(b, 1, h * dv).astype(x.dtype)
+    with jax.named_scope("attn.latent"):
+        w_uk = p["wkv_b"].reshape(cfg.kv_lora, h, dn + dv)[..., :dn]  # (L,H,dn)
+        w_uv = p["wkv_b"].reshape(cfg.kv_lora, h, dn + dv)[..., dn:]  # (L,H,dv)
+        q_abs = jnp.einsum("bhd,lhd->bhl", q_nope[:, 0].astype(jnp.float32), w_uk)
+        sc = (
+            jnp.einsum("bhl,bsl->bhs", q_abs, c_cache.astype(jnp.float32))
+            + jnp.einsum("bhd,bsd->bhs", q_rope[:, 0].astype(jnp.float32), r_cache.astype(jnp.float32))
+        ) * cfg.softmax_scale
+        smax = c_cache.shape[1]
+        valid = jnp.arange(smax)[None, :] <= idx[:, None]
+        sc = jnp.where(valid[:, None, :], sc, -1e30)
+        wts = jax.nn.softmax(sc, axis=-1)
+        ctx = jnp.einsum("bhs,bsl->bhl", wts, c_cache.astype(jnp.float32))
+        out = jnp.einsum("bhl,lhd->bhd", ctx, w_uv).reshape(b, 1, h * dv).astype(x.dtype)
     out = site_matmul(ftc, "attn.out")(out, p["wo"])
     return out, {"c_kv": c_cache, "k_rope": r_cache, "idx": idx + 1}

@@ -7,6 +7,7 @@ models on one host CPU and to keep dry-run compiles fast.
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Callable
 
@@ -81,10 +82,46 @@ def rope_freqs(head_dim: int, theta: float = 10000.0) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0) -> jax.Array:
-    """x: (..., S, H, D); positions: (..., S)."""
+@dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN rope scaling (Peng et al., arXiv:2309.00071) in DeepSeek-V2's
+    form: the ``rope_scaling`` group of its config.json."""
+    factor: float
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature for a context stretched ``factor`` times."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_freqs(head_dim: int, theta: float, s: YaRN) -> jax.Array:
+    """Rotary frequencies of each pair ``i`` of ``head_dim``: the plain
+    frequency for the fast-rotating pairs (more than ``beta_fast`` turns over
+    the original context), the plain one divided by ``factor`` for the slow
+    ones (fewer than ``beta_slow`` turns), a linear ramp between them."""
+    def turns_dim(turns: float) -> float:
+        return head_dim * math.log(s.original_max_position / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(turns_dim(s.beta_fast)), 0)
+    high = min(math.ceil(turns_dim(s.beta_slow)), head_dim - 1)
+    extra = rope_freqs(head_dim, theta)
+    ramp = (jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / max(high - low, 1e-3)
+    m = 1.0 - jnp.clip(ramp, 0.0, 1.0)
+    return extra / s.factor * (1.0 - m) + extra * m
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
+               freqs: jax.Array | None = None) -> jax.Array:
+    """x: (..., S, H, D); positions: (..., S).  ``freqs`` (D/2,) replaces the
+    plain frequencies of ``theta`` (rope scaling)."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)
+    if freqs is None:
+        freqs = rope_freqs(d, theta)
     ang = positions[..., :, None, None].astype(jnp.float32) * freqs  # (...,S,1,D/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)

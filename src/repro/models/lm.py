@@ -58,7 +58,7 @@ from repro.models.layers import (
     stack_layer_params,
 )
 from repro.models.mamba2 import Mamba2Config, mamba2_cache_init, mamba2_decode, mamba2_forward, mamba2_init
-from repro.models.moe import MoEConfig, moe_forward, moe_init
+from repro.models.moe import MoEConfig, moe_decode, moe_forward, moe_init
 from repro.models.rwkv6 import RWKV6Config, rwkv6_cache_init, rwkv6_decode, rwkv6_forward, rwkv6_init
 
 _ACTS = {"silu": jax.nn.silu, "gelu": jax.nn.gelu, "relu": jax.nn.relu}
@@ -143,8 +143,10 @@ class LMConfig:
             return total
         m = self.moe
         per_expert = 3 * self.d_model * m.d_expert
-        inactive = (m.n_padded - m.top_k) * per_expert * (self.n_layers - self.first_k_dense)
-        return total - inactive
+        # a share holds n_held experts, of which top_k * n_held / n_padded are routed to
+        routed = m.top_k * m.n_held / m.n_padded
+        inactive = (m.n_held - routed) * per_expert * (self.n_layers - self.first_k_dense)
+        return int(total - inactive)
 
 
 # --------------------------------------------------------------------------- #
@@ -473,6 +475,9 @@ def init_cache(cfg: LMConfig, batch: int, smax: int, dtype=jnp.bfloat16) -> Para
         cache: Params = {"attn": _stackN(one, cfg.n_layers - cfg.first_k_dense)}
         if cfg.first_k_dense:
             cache["attn_dense"] = _stackN(one, cfg.first_k_dense)
+        if cfg.family == "moe":   # the last step's routing, written by decode_step
+            cache["moe_load"] = jnp.zeros(
+                (cfg.n_layers - cfg.first_k_dense, batch, cfg.moe.n_held), jnp.int32)
         return cache
     if cfg.family == "ssm":
         return {"rwkv": _stackN(rwkv6_cache_init(cfg.rwkv, batch), cfg.n_layers)}
@@ -525,6 +530,11 @@ def decode_step(
 ) -> tuple[jax.Array, Params]:
     """batch: {"token": (B, 1) int32}.  Returns (logits (B,1,V), new cache).
 
+    An MoE model's new cache also holds the step's routing under
+    ``"moe_load"``: int32 (MoE layers, B, E_held), 1 where the row's token
+    was dispatched to the held expert in that layer (the server reads it
+    with the sampled tokens while a trace records, docs/observability.md).
+
     ``ftc`` mirrors :func:`forward`'s execution context: every weight matmul
     of the protected layer prefix — attention projections, FFN, MoE router +
     experts, SSM/RWKV projections — plus the LM head routes through the
@@ -550,7 +560,7 @@ def decode_step(
             x, cd = _decode_scan(fd, x, (blocks, cache["attn_dense"]), cfg)
             new_cache["attn_dense"] = cd
         n_main = cfg.n_layers - cfg.first_k_dense
-        cache_parts = []
+        cache_parts, loads = [], []
         for lo, hi, fc in _layer_splits(n_main, ftc):
             blocks = _cast(_slice_layers(params["blocks"], lo, hi), cfg.dtype)
             def f(x, inp, fc=fc):
@@ -558,13 +568,19 @@ def decode_step(
                 h, c2 = _attn_decode(_norm(x, lp["ln1"], cfg), lp["attn"], cfg, c, fc)
                 x = x + h
                 if is_moe:
-                    y, _ = moe_forward(_norm(x, lp["ln2"], cfg), lp["moe"], cfg.moe, ftc=fc)
+                    y, load = moe_decode(_norm(x, lp["ln2"], cfg), lp["moe"], cfg.moe, ftc=fc)
+                    c2 = (c2, load)
                 else:
                     y = ffn(_norm(x, lp["ln2"], cfg), lp["ffn"], act=act, ftc=fc)
                 return shard(x + y, "batch", None, "embed"), c2
             x, ca = _decode_scan(f, x, (blocks, _slice_layers(cache["attn"], lo, hi)), cfg)
+            if is_moe:
+                ca, load = ca
+                loads.append(load)
             cache_parts.append(ca)
         new_cache["attn"] = _concat_cache_parts(cache_parts)
+        if is_moe:
+            new_cache["moe_load"] = jnp.concatenate(loads)
 
     elif cfg.family == "ssm":
         cache_parts = []

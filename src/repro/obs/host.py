@@ -10,6 +10,7 @@ recorded; with no trace running a span costs one object and one native call
 The span tree of one ``FaultTolerantServer.step()`` (docs/observability.md):
 
     hyca.server.step          step, active, positions, tokens, queue
+                              (+ moe_pairs, moe_max_load for an MoE model)
       hyca.fault.inject       (fault_rate > 0 only)
       hyca.fault.scan
         hyca.fault.scan.sync    every device->host readback of the scan
@@ -19,7 +20,8 @@ The span tree of one ``FaultTolerantServer.step()`` (docs/observability.md):
       hyca.cache.reset        (only when a slot was admitted)
       hyca.decode.feed        plan_feed, the fault state, the feed's copy
       hyca.decode.dispatch    the jitted decode step's dispatch
-      hyca.decode.sample      argmax and its device->host copy
+      hyca.decode.sample      argmax and its device->host copy (with the
+                              routing load, for a traced MoE step)
       hyca.sched.commit
       hyca.metrics.record     StepRecord and the series append
 

@@ -486,7 +486,13 @@ class FaultTolerantServer:
                         self.params, self.cache, tok, fstate, self.plan,
                     )
             with span("decode.sample"):
-                sampled = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1), np.int32)
+                best = jnp.argmax(logits[:, -1, :], axis=-1)
+                load = self.cache.get("moe_load") if positions is not None else None
+                if load is not None:   # traced MoE step: one readback for tokens and load
+                    best, load = jax.device_get((best, load))
+                    busy = np.array([not s.free for s in self.scheduler.slots])
+                    load = load[:, busy].sum(1)          # (MoE layers, held experts)
+                sampled = np.asarray(best, np.int32)
 
             # 6. advance requests
             with span("sched.commit"):
@@ -535,6 +541,8 @@ class FaultTolerantServer:
             if positions is not None:
                 root.set_metadata(active=n_active, positions=positions,
                                   tokens=int(n_decode_tokens), queue=self.queue.depth())
+                if load is not None:
+                    root.set_metadata(moe_pairs=int(load.sum()), moe_max_load=int(load.max()))
         return completed
 
     # ------------------------------------------------------------------ #

@@ -12,6 +12,9 @@ program holds the Pallas kernel (``tpu_custom_call``):
     ``FTContext`` dispatch (block from the bf16 heuristic, rows padded);
   * ``ft_matmul_batched`` at deepseek-moe-16b's expert shapes (64 experts,
     d_model 2048, d_expert 1408);
+  * ``ft_matmul_batched`` fed bf16 through the fused ``FTContext`` expert
+    dispatch at deepseek-v2-lite's decode share (128 slots, 16 held
+    experts, d_model 2048, d_expert 1408);
   * ``probe_check`` at the server's probe shapes (8x8 and 32x32 arrays) and
     at K > window.
 
@@ -116,6 +119,21 @@ def test_ft_matmul_batched_compiles(one_chip, k, n):
         lambda x, w, meta: ft_matmul_batched(x, w, meta, bm=8, bn=128, bk=128),
         _sds((e, m, k), jnp.float32, one_chip), _sds((e, k, n), jnp.float32, one_chip),
         _sds((ROWS, COLS), jnp.int32, one_chip),
+    )
+
+
+@pytest.mark.parametrize("spec,k,n", [("becd,edf->becf", 2048, 1408),
+                                       ("becf,efd->becd", 1408, 2048)])
+def test_fused_expert_dispatch_bf16_compiles_at_the_v2lite_share(one_chip, spec, k, n):
+    """128 decode slots' one-token groups over 16 held experts, bf16."""
+    hyca = dla_config()
+    ctx = dataclasses.replace(
+        build_ftcontext(empty_fault_state(hyca.rows * hyca.cols), hyca, dispatch="fused"),
+        fused_backend="pallas",
+    )
+    _assert_kernel(
+        lambda x, w: ctx.einsum(spec, x, w, site="moe.expert"),
+        _sds((128, 16, 1, k), jnp.bfloat16, one_chip), _sds((16, k, n), jnp.bfloat16, one_chip),
     )
 
 
