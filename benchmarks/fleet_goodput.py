@@ -31,9 +31,11 @@ from repro.serving import ChaosSpec, FleetConfig, ServerConfig, TrafficSpec
 from repro.serving.fleet import run_fleet
 from repro.serving.vfleet import _TRACES, run_vfleet
 
+# prefill_chunk=1: every prefilling slot feeds one prompt token a step, the
+# feed the vectorized engine's decode proxy models (run_vfleet refuses more)
 _SERVER = ServerConfig(
     n_slots=4, smax=64, mode="protected", scan_block=2,
-    rows=8, cols=8, dppu_size=4,
+    rows=8, cols=8, dppu_size=4, prefill_chunk=1,
 )
 _TRAFFIC = TrafficSpec(
     request_rate=0.3, sla_steps=64, seed=2, n_classes=2, tail=0.4,
@@ -142,7 +144,8 @@ def run(quick: bool = False) -> dict:
         chaos=ChaosSpec(per=0.3, at_step=10, seed=3),
         traffic=TrafficSpec(request_rate=0.8, sla_steps=12, seed=5),
         server=ServerConfig(n_slots=2, smax=32, mode="protected",
-                            scan_block=2, rows=4, cols=4, dppu_size=2),
+                            scan_block=2, rows=4, cols=4, dppu_size=2,
+                            prefill_chunk=1),
     )
     legacy = run_fleet(parity_cfg)
     vec = run_vfleet(parity_cfg)

@@ -43,9 +43,10 @@ from repro.serving.vfleet import run_vfleet
 
 OVERHEAD_BUDGET_X = 1.10
 
+# prefill_chunk=1: the one-token prompt feed that run_vfleet models
 _SERVER = ServerConfig(
     n_slots=4, smax=64, mode="protected", scan_block=2,
-    rows=8, cols=8, dppu_size=4,
+    rows=8, cols=8, dppu_size=4, prefill_chunk=1,
 )
 
 

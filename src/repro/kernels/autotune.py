@@ -125,14 +125,22 @@ def sublane_tile(dtype) -> int:
     return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
 
 
+# a matmul of at most this many rows runs as one row block of the kernel
+# grid: each weight tile is then read once (a decode step with its prompt
+# chunk is bound by weight reads, not by the rows' FLOPs)
+ONE_BLOCK_ROWS = 512
+
+
 def default_block(m: int, n: int, k: int, *, backend: str = "pallas",
                   dtype=jnp.float32) -> tuple[int, int, int]:
-    """Shape-aware heuristic for shapes the cache has not seen: full MXU
-    tiles, except bm shrinks (in sublane-tile steps of the operand dtype)
-    for skinny activations, so a (4, N) decode row is padded to 8 rows in
-    f32 or 16 in bf16, not 128."""
+    """Shape-aware heuristic for shapes the cache has not seen: 128-wide
+    MXU tiles in n and k; in m, every row in one block (in sublane-tile
+    steps of the operand dtype) up to :data:`ONE_BLOCK_ROWS`, so a (4, N)
+    decode row is padded to 8 rows in f32 or 16 in bf16 and a decode step's
+    320 rows read each weight tile once, and 128-row blocks beyond."""
     del backend  # same heuristic everywhere the kernel runs
-    return (min(128, _round_up(max(m, 1), sublane_tile(dtype))), 128, 128)
+    rows = _round_up(max(m, 1), sublane_tile(dtype))
+    return (rows if rows <= ONE_BLOCK_ROWS else 128, 128, 128)
 
 
 def validate_fused_block(block, *, backend: str, dtype=jnp.float32) -> tuple[int, int, int]:

@@ -124,25 +124,77 @@ def gqa_forward(x, p, cfg: AttnConfig, positions=None, unroll: bool = False, ftc
     return _shard(out, "batch", "seq", "embed")  # bf16 reshard point (§Perf)
 
 
-def gqa_decode(x, p, cfg: AttnConfig, cache: Params, ftc=None) -> tuple[jax.Array, Params]:
-    """One-token decode. x: (B,1,d); cache: {k,v: (B,Smax,Hk,D), idx: (B,)}."""
-    b = x.shape[0]
+def _step_rows(idx: jax.Array, n_rows: int, advance=None, chunk=None):
+    """Cache slot, position and write flag of each row of a decode step, and
+    the cache lengths after it.
+
+    The first ``B = idx.shape[0]`` rows are the slots' own tokens: row ``b``
+    sits at position ``idx[b]`` of slot ``b`` and writes there where
+    ``advance[b]`` (every row by default).  With ``chunk`` (``{"slot": (),
+    "len": ()}`` int32) the remaining ``n_rows - B`` rows are a prompt chunk
+    of slot ``chunk["slot"]`` at positions ``idx[slot], idx[slot] + 1, …``;
+    the first ``chunk["len"]`` of them write, the padding past it writes
+    nothing.  A row that does not write leaves every cache leaf as it was.
+    """
+    b = idx.shape[0]
+    slot = jnp.arange(b)
+    pos = idx
+    write = jnp.ones((b,), bool) if advance is None else advance
+    new_idx = idx + write.astype(idx.dtype)
+    if chunk is not None:
+        i = jnp.arange(n_rows - b)
+        slot = jnp.concatenate([slot, jnp.full(i.shape, chunk["slot"], slot.dtype)])
+        pos = jnp.concatenate([pos, idx[chunk["slot"]] + i])
+        write = jnp.concatenate([write, i < chunk["len"]])
+        new_idx = new_idx.at[chunk["slot"]].add(chunk["len"])
+    return slot, pos, write, new_idx
+
+
+def _write_rows(leaf, slot, pos, write, new):
+    """``leaf[slot[r], pos[r]] = new[r]`` for the rows that write."""
+    drop = jnp.where(write, pos, leaf.shape[1])      # out of range: dropped
+    return leaf.at[slot, drop].set(new.astype(leaf.dtype), mode="drop")
+
+
+def _chunk_view(a, b: int):
+    """Rows ``b:`` of a step's ``(rows, 1, …)`` array as one sequence ``(1, C, …)``."""
+    return a[b:].swapaxes(0, 1)
+
+
+def _cached_attention(q, k, v, qpos, scale):
+    """q: (N,Q,Hk,G,D) at positions qpos (N,Q) over k, v: (N,S,Hk,D); a
+    query sees the positions up to its own.  Returns (N,Q,Hk,G,D) fp32."""
+    sc = _grouped_scores(q, k, scale)  # (N,Q,Hk,G,S)
+    valid = jnp.arange(k.shape[1]) <= qpos[..., None]  # (N,Q,S)
+    sc = jnp.where(valid[:, :, None, None, :], sc, -1e30)
+    wts = jax.nn.softmax(sc, axis=-1)
+    return jnp.einsum("bqhgs,bshd->bqhgd", wts, v.astype(jnp.float32))
+
+
+def gqa_decode(x, p, cfg: AttnConfig, cache: Params, ftc=None, *, advance=None,
+               chunk=None) -> tuple[jax.Array, Params]:
+    """One decode step. x: (B,1,d), one token a slot; cache: {k,v:
+    (B,Smax,Hk,D), idx: (B,)}.  With ``chunk`` (see :func:`_step_rows`), x is
+    (B + C, 1, d): the slots' tokens, then C prompt tokens of one slot, which
+    attend causally to that slot's cache and to each other.  The projections
+    run over all rows at once; ``advance`` masks which slot rows write."""
+    b = cache["idx"].shape[0]
     idx = cache["idx"]  # (B,) current length
-    q, k_new, v_new = _qkv(x, p, cfg, idx[:, None], ftc)
-    bidx = jnp.arange(b)
-    k_cache = cache["k"].at[bidx, idx].set(k_new[:, 0].astype(cache["k"].dtype))
-    v_cache = cache["v"].at[bidx, idx].set(v_new[:, 0].astype(cache["v"].dtype))
-    smax = k_cache.shape[1]
+    slot, pos, write, new_idx = _step_rows(idx, x.shape[0], advance, chunk)
+    q, k_new, v_new = _qkv(x, p, cfg, pos[:, None], ftc)
+    k_cache = _write_rows(cache["k"], slot, pos, write, k_new[:, 0])
+    v_cache = _write_rows(cache["v"], slot, pos, write, v_new[:, 0])
     g = cfg.n_heads // cfg.n_kv
     scale = 1.0 / (cfg.hd ** 0.5)
-    qh = q.reshape(b, 1, cfg.n_kv, g, cfg.hd)
-    sc = _grouped_scores(qh, k_cache, scale)[:, 0]  # (B,Hk,G,S)
-    valid = jnp.arange(smax)[None, :] <= idx[:, None]  # (B,S)
-    sc = jnp.where(valid[:, None, None, :], sc, -1e30)
-    wts = jax.nn.softmax(sc, axis=-1)
-    out = jnp.einsum("bhgs,bshd->bhgd", wts, v_cache.astype(jnp.float32))
-    out = out.reshape(b, 1, cfg.n_heads * cfg.hd).astype(x.dtype)
-    new_cache = {"k": k_cache, "v": v_cache, "idx": idx + 1}
+    qh = q.reshape(-1, 1, cfg.n_kv, g, cfg.hd)
+    out = _cached_attention(qh[:b], k_cache, v_cache, idx[:, None], scale)
+    if chunk is not None:
+        s = chunk["slot"]
+        out_c = _cached_attention(_chunk_view(qh, b), k_cache[s][None], v_cache[s][None],
+                                  pos[None, b:], scale)
+        out = jnp.concatenate([out, out_c.swapaxes(0, 1)])
+    out = out.reshape(-1, 1, cfg.n_heads * cfg.hd).astype(x.dtype)
+    new_cache = {"k": k_cache, "v": v_cache, "idx": new_idx}
     return site_matmul(ftc, "attn.out")(out, p["wo"]), new_cache
 
 
@@ -277,9 +329,30 @@ def mla_cache_init(cfg: MLAConfig, batch: int, smax: int, dtype=jnp.bfloat16) ->
     }
 
 
-def mla_decode(x, p, cfg: MLAConfig, cache: Params, ftc=None) -> tuple[jax.Array, Params]:
+def _latent_attention(q_nope, q_rope, c, r, qpos, w_uk, w_uv, scale):
+    """Absorbed attention of q_nope (N,Q,H,dn), q_rope (N,Q,H,dr) at
+    positions qpos (N,Q) over a latent cache c (N,S,L) and its rope keys r
+    (N,S,dr); a query sees the positions up to its own.  Returns (N,Q,H,dv)
+    fp32."""
+    q_abs = jnp.einsum("bqhd,lhd->bqhl", q_nope.astype(jnp.float32), w_uk)
+    sc = (
+        jnp.einsum("bqhl,bsl->bqhs", q_abs, c.astype(jnp.float32))
+        + jnp.einsum("bqhd,bsd->bqhs", q_rope.astype(jnp.float32), r.astype(jnp.float32))
+    ) * scale
+    valid = jnp.arange(c.shape[1]) <= qpos[..., None]  # (N,Q,S)
+    sc = jnp.where(valid[:, :, None, :], sc, -1e30)
+    wts = jax.nn.softmax(sc, axis=-1)
+    ctx = jnp.einsum("bqhs,bsl->bqhl", wts, c.astype(jnp.float32))
+    return jnp.einsum("bqhl,lhd->bqhd", ctx, w_uv)
+
+
+def mla_decode(x, p, cfg: MLAConfig, cache: Params, ftc=None, *, advance=None,
+               chunk=None) -> tuple[jax.Array, Params]:
     """Absorbed-matmul decode: attention runs in the compressed latent space so
-    the cache stays (kv_lora + d_rope) per token — MLA's whole point.
+    the cache stays (kv_lora + d_rope) per token — MLA's whole point.  Rows,
+    ``advance`` and ``chunk`` as in :func:`gqa_decode`: a prompt chunk's
+    rows share the projections with the slots' rows and attend causally to
+    their slot's latent cache.
 
     The absorbed latent einsums (w_uk / w_uv contractions) run off the
     protected array: they are reshaped views of ``wkv_b``, which *is*
@@ -288,26 +361,24 @@ def mla_decode(x, p, cfg: MLAConfig, cache: Params, ftc=None) -> tuple[jax.Array
     over the latent cache and the softmax run under the device scope
     ``attn.latent``.
     """
-    b = x.shape[0]
+    b = cache["idx"].shape[0]
     idx = cache["idx"]
     h, dn, dv = cfg.n_heads, cfg.d_nope, cfg.d_v
-    q_nope, q_rope, c_kv_new, k_rope_new = _mla_qkr(x, p, cfg, idx[:, None], ftc)
-    bidx = jnp.arange(b)
-    c_cache = cache["c_kv"].at[bidx, idx].set(c_kv_new[:, 0].astype(cache["c_kv"].dtype))
-    r_cache = cache["k_rope"].at[bidx, idx].set(k_rope_new[:, 0].astype(cache["k_rope"].dtype))
+    slot, pos, write, new_idx = _step_rows(idx, x.shape[0], advance, chunk)
+    q_nope, q_rope, c_kv_new, k_rope_new = _mla_qkr(x, p, cfg, pos[:, None], ftc)
+    c_cache = _write_rows(cache["c_kv"], slot, pos, write, c_kv_new[:, 0])
+    r_cache = _write_rows(cache["k_rope"], slot, pos, write, k_rope_new[:, 0])
     with jax.named_scope("attn.latent"):
         w_uk = p["wkv_b"].reshape(cfg.kv_lora, h, dn + dv)[..., :dn]  # (L,H,dn)
         w_uv = p["wkv_b"].reshape(cfg.kv_lora, h, dn + dv)[..., dn:]  # (L,H,dv)
-        q_abs = jnp.einsum("bhd,lhd->bhl", q_nope[:, 0].astype(jnp.float32), w_uk)
-        sc = (
-            jnp.einsum("bhl,bsl->bhs", q_abs, c_cache.astype(jnp.float32))
-            + jnp.einsum("bhd,bsd->bhs", q_rope[:, 0].astype(jnp.float32), r_cache.astype(jnp.float32))
-        ) * cfg.softmax_scale
-        smax = c_cache.shape[1]
-        valid = jnp.arange(smax)[None, :] <= idx[:, None]
-        sc = jnp.where(valid[:, None, :], sc, -1e30)
-        wts = jax.nn.softmax(sc, axis=-1)
-        ctx = jnp.einsum("bhs,bsl->bhl", wts, c_cache.astype(jnp.float32))
-        out = jnp.einsum("bhl,lhd->bhd", ctx, w_uv).reshape(b, 1, h * dv).astype(x.dtype)
+        out = _latent_attention(q_nope[:b], q_rope[:b], c_cache, r_cache, idx[:, None],
+                                w_uk, w_uv, cfg.softmax_scale)
+        if chunk is not None:
+            s = chunk["slot"]
+            out_c = _latent_attention(_chunk_view(q_nope, b), _chunk_view(q_rope, b),
+                                      c_cache[s][None], r_cache[s][None], pos[None, b:],
+                                      w_uk, w_uv, cfg.softmax_scale)
+            out = jnp.concatenate([out, out_c.swapaxes(0, 1)])
+        out = out.reshape(-1, 1, h * dv).astype(x.dtype)
     out = site_matmul(ftc, "attn.out")(out, p["wo"])
-    return out, {"c_kv": c_cache, "k_rope": r_cache, "idx": idx + 1}
+    return out, {"c_kv": c_cache, "k_rope": r_cache, "idx": new_idx}

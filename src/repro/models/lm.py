@@ -495,10 +495,24 @@ def init_cache(cfg: LMConfig, batch: int, smax: int, dtype=jnp.bfloat16) -> Para
     raise ValueError(cfg.family)
 
 
-def _attn_decode(x, p, cfg: LMConfig, cache, ftc: FTContext | None = None):
+# families whose decode step takes a prompt chunk beside the slots' tokens
+# (attention caches only: a recurrent state has no positions to write into)
+CHUNK_FAMILIES = ("dense", "vlm", "moe")
+
+
+def _attn_decode(x, p, cfg: LMConfig, cache, ftc: FTContext | None = None, **rows):
     if cfg.attn_kind == "mla":
-        return mla_decode(x, p, cfg.mla, cache, ftc)
-    return gqa_decode(x, p, cfg.attn_cfg, cache, ftc)
+        return mla_decode(x, p, cfg.mla, cache, ftc, **rows)
+    return gqa_decode(x, p, cfg.attn_cfg, cache, ftc, **rows)
+
+
+def _fold_chunk_load(load, b: int, chunk):
+    """(B + C, E) routing of a step's rows -> (B, E): the chunk's real rows
+    summed into its slot's row, whose own token fed nothing."""
+    real = (jnp.arange(load.shape[0] - b) < chunk["len"])[:, None]
+    mine = jnp.where(real, load[b:], 0).sum(0)
+    s = chunk["slot"]
+    return load[:b].at[s].set(jnp.where(chunk["len"] > 0, mine, load[s]))
 
 
 def _decode_scan(f, x, xs, cfg: LMConfig):
@@ -530,10 +544,22 @@ def decode_step(
 ) -> tuple[jax.Array, Params]:
     """batch: {"token": (B, 1) int32}.  Returns (logits (B,1,V), new cache).
 
+    A family of :data:`CHUNK_FAMILIES` also takes a prompt chunk in the
+    same step: ``batch["chunk"]`` = {"tokens": (C,) int32, "slot": (),
+    "len": ()} — the next ``len`` prompt tokens of slot ``slot``, padded to
+    C — and ``batch["advance"]`` (B,) bool, the slots whose token this step
+    feeds.  The chunk's rows are appended to the slots' rows for every
+    weight matmul (one call per site for both), attend causally to their
+    slot's cache, and write their K/V (or latent) rows there; padding and
+    the slots that do not advance write nothing.  The slot's row of the
+    logits then holds the logits of the chunk's last real token (unchanged
+    when ``len`` is 0).
+
     An MoE model's new cache also holds the step's routing under
     ``"moe_load"``: int32 (MoE layers, B, E_held), 1 where the row's token
-    was dispatched to the held expert in that layer (the server reads it
-    with the sampled tokens while a trace records, docs/observability.md).
+    was dispatched to the held expert in that layer, a chunk slot's row
+    counting its chunk's tokens (the server reads it with the sampled
+    tokens while a trace records, docs/observability.md).
 
     ``ftc`` mirrors :func:`forward`'s execution context: every weight matmul
     of the protected layer prefix — attention projections, FFN, MoE router +
@@ -542,18 +568,26 @@ def decode_step(
     main-stack scan statically, so unprotected layers lower plain matmuls.
     """
     tok = batch["token"]
+    b = tok.shape[0]
+    chunk = batch.get("chunk")
+    rows = {}
+    if chunk is not None:
+        if cfg.family not in CHUNK_FAMILIES:
+            raise ValueError(f"the {cfg.family} family takes no prompt chunk")
+        tok = jnp.concatenate([tok, chunk["tokens"][:, None]])
+        rows = {"advance": batch["advance"], "chunk": chunk}
     x = params["embed"].astype(cfg.dtype)[tok]
     x = shard(x, "batch", None, "embed")
     act = _ACTS[cfg.act]
 
-    if cfg.family in ("dense", "vlm", "moe"):
+    if cfg.family in CHUNK_FAMILIES:
         is_moe = cfg.family == "moe"
         new_cache = dict(cache)
         if cfg.first_k_dense:
             blocks = _cast(params["dense_blocks"], cfg.dtype)
             def fd(x, inp):
                 lp, c = inp
-                h, c2 = _attn_decode(_norm(x, lp["ln1"], cfg), lp["attn"], cfg, c, ftc)
+                h, c2 = _attn_decode(_norm(x, lp["ln1"], cfg), lp["attn"], cfg, c, ftc, **rows)
                 x = x + h
                 x = x + ffn(_norm(x, lp["ln2"], cfg), lp["ffn"], act=act, ftc=ftc)
                 return x, c2
@@ -565,10 +599,12 @@ def decode_step(
             blocks = _cast(_slice_layers(params["blocks"], lo, hi), cfg.dtype)
             def f(x, inp, fc=fc):
                 lp, c = inp
-                h, c2 = _attn_decode(_norm(x, lp["ln1"], cfg), lp["attn"], cfg, c, fc)
+                h, c2 = _attn_decode(_norm(x, lp["ln1"], cfg), lp["attn"], cfg, c, fc, **rows)
                 x = x + h
                 if is_moe:
                     y, load = moe_decode(_norm(x, lp["ln2"], cfg), lp["moe"], cfg.moe, ftc=fc)
+                    if chunk is not None:
+                        load = _fold_chunk_load(load, b, chunk)
                     c2 = (c2, load)
                 else:
                     y = ffn(_norm(x, lp["ln2"], cfg), lp["ffn"], act=act, ftc=fc)
@@ -581,6 +617,9 @@ def decode_step(
         new_cache["attn"] = _concat_cache_parts(cache_parts)
         if is_moe:
             new_cache["moe_load"] = jnp.concatenate(loads)
+        if chunk is not None:   # the chunk's last real row stands in its slot's row
+            s, last = chunk["slot"], b + jnp.maximum(chunk["len"] - 1, 0)
+            x = x[:b].at[s].set(jnp.where(chunk["len"] > 0, x[last], x[s]))
 
     elif cfg.family == "ssm":
         cache_parts = []

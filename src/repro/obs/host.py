@@ -9,8 +9,8 @@ recorded; with no trace running a span costs one object and one native call
 
 The span tree of one ``FaultTolerantServer.step()`` (docs/observability.md):
 
-    hyca.server.step          step, active, positions, tokens, queue
-                              (+ moe_pairs, moe_max_load for an MoE model)
+    hyca.server.step          step, active, positions, tokens, prefill_tokens,
+                              queue (+ moe_pairs, moe_max_load for an MoE model)
       hyca.fault.inject       (fault_rate > 0 only)
       hyca.fault.scan
         hyca.fault.scan.sync    every device->host readback of the scan
@@ -18,7 +18,8 @@ The span tree of one ``FaultTolerantServer.step()`` (docs/observability.md):
       hyca.repair             (only when the repair hook plans)
       hyca.sched.admit        capacity limit, admission, expiries
       hyca.cache.reset        (only when a slot was admitted)
-      hyca.decode.feed        plan_feed, the fault state, the feed's copy
+      hyca.decode.feed        plan_feed (with the prompt chunk), the fault
+                              state, the feed's copy
       hyca.decode.dispatch    the jitted decode step's dispatch
       hyca.decode.sample      argmax and its device->host copy (with the
                               routing load, for a traced MoE step)

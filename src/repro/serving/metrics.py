@@ -47,6 +47,7 @@ class StepRecord:
     completed: int
     remapped: int = 0              # PEs handled model-side (repro.repair)
     quality_fraction: float = 1.0  # fraction of columns with trusted output
+    prefill_tokens: int = 0        # prompt tokens the step's chunk fed
 
 
 class ServingMetrics:
@@ -163,6 +164,7 @@ class ServingMetrics:
             "wall_s": self.wall_s,
             "tokens": toks,
             "tokens_per_step": toks / max(n_steps, 1),
+            "prefill_tokens_per_step": sum(r.prefill_tokens for r in self.steps) / max(n_steps, 1),
             "tokens_per_s": toks / max(self.wall_s, 1e-9),
             "goodput_tokens": good,
             "goodput_per_step": good / max(n_steps, 1),

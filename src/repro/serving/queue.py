@@ -34,11 +34,18 @@ class Request:
     def prompt_len(self) -> int:
         return int(self.prompt.size)
 
-    def min_steps_to_finish(self) -> int:
-        """Lower bound on steps from admission to completion (prefill is one
-        prompt token per step, then one generated token per step; the first
-        generated token rides the final prefill step)."""
-        return self.prompt_len + self.max_new_tokens - 1
+    def positions_needed(self) -> int:
+        """KV-cache positions the request may occupy: its prompt and its
+        generation budget."""
+        return self.prompt_len + self.max_new_tokens
+
+    def min_steps_to_finish(self, prefill_chunk: int = 0) -> int:
+        """Lower bound on steps from admission to completion: the prompt fed
+        ``prefill_chunk`` tokens a step (one a step where the model takes no
+        chunk, ``prefill_chunk`` 0), then one generated token per step; the
+        first generated token rides the final prefill step."""
+        per_step = max(prefill_chunk, 1)
+        return -(-self.prompt_len // per_step) + self.max_new_tokens - 1
 
 
 @dataclasses.dataclass
@@ -67,9 +74,12 @@ class CompletedRequest:
 
 
 class RequestQueue:
-    """FIFO with SLA-aware admission."""
+    """FIFO with SLA-aware admission.  ``prefill_chunk``: the prompt tokens
+    a step can feed one request (the scheduler's chunk size, 0 for one a
+    step), which sets the steps a request needs at the least."""
 
-    def __init__(self):
+    def __init__(self, prefill_chunk: int = 0):
+        self.prefill_chunk = prefill_chunk
         self._q: deque[Request] = deque()
         self._expired: list[Request] = []
         # optional repro.obs EventLog (the server wires its own): request
@@ -94,11 +104,12 @@ class RequestQueue:
         """Next request that can still meet its deadline if admitted now;
         unmeetable requests are dropped into the expired list.  A request
         admitted at ``step`` finishes no earlier than step
-        ``step + min_steps_to_finish() - 1`` (the first prompt token is fed
-        at the admission step itself)."""
+        ``step + min_steps_to_finish(prefill_chunk) - 1`` (the first prompt
+        tokens are fed at the admission step itself)."""
         while self._q:
             req = self._q.popleft()
-            if req.deadline_step is not None and step + req.min_steps_to_finish() - 1 > req.deadline_step:
+            if (req.deadline_step is not None
+                    and step + req.min_steps_to_finish(self.prefill_chunk) - 1 > req.deadline_step):
                 self._expired.append(req)
                 if self.log is not None:
                     self.log.emit("request.complete", step=step,

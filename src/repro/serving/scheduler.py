@@ -1,13 +1,21 @@
 """Continuous batching: iteration-level scheduling over fixed decode slots.
 
-Every server step runs ONE batched decode over all ``n_slots`` cache slots.
-Each slot independently advances its own request through two phases:
+Every server step runs ONE batched decode over all ``n_slots`` cache slots,
+one row a slot, and — where the model's family takes one
+(``repro.models.lm.CHUNK_FAMILIES``) — one prompt chunk of up to
+``prefill_chunk`` tokens riding in the same step.  Each slot independently
+advances its own request through two phases:
 
-  * PREFILL — the slot feeds its next prompt token each step (token-level
-    chunked prefill: the prompt streams through the same decode path that
-    generation uses, one token per step, against the slot's own KV cache).
-    The logits of the *last* prompt token yield the first generated token,
-    so TTFT is measured at that step.
+  * PREFILL — the prompt streams through the same decode path that
+    generation uses, against the slot's own KV cache.  The prefilling slot
+    admitted earliest feeds its next ``prefill_chunk`` prompt tokens as the
+    step's chunk (its own row feeds nothing); every other prefilling slot
+    feeds its next prompt token through its row.  A step with no
+    prefilling slot has no chunk, and the server then runs the token-only
+    step.  Without a chunk (SSM, hybrid, enc-dec) every prefilling slot
+    feeds one token a step.  The
+    logits of the *last* prompt token yield the first generated token, so
+    TTFT is measured at that step.
   * DECODE — the slot feeds its previously generated token and appends the
     newly sampled one.
 
@@ -30,6 +38,15 @@ from repro.serving.queue import CompletedRequest, Request, RequestQueue
 
 PREFILL = "prefill"
 DECODE = "decode"
+
+
+@dataclasses.dataclass(frozen=True)
+class Chunk:
+    """The step's prompt chunk: ``n`` prompt tokens of slot ``slot``, in
+    ``tokens`` padded with zeros to the chunk size."""
+    slot: int
+    tokens: np.ndarray
+    n: int
 
 
 @dataclasses.dataclass
@@ -56,14 +73,22 @@ class Slot:
 
 
 class ContinuousBatchingScheduler:
-    def __init__(self, n_slots: int, smax: int):
+    """``prefill_chunk``: prompt tokens the step's chunk holds, 0 where the
+    model takes no chunk (every prefilling slot then feeds one a step)."""
+
+    def __init__(self, n_slots: int, smax: int, prefill_chunk: int = 0):
         if n_slots < 1:
             raise ValueError("need at least one slot")
+        if prefill_chunk < 0:
+            raise ValueError("prefill_chunk must be >= 0")
         self.n_slots = n_slots
         self.smax = smax
+        self.prefill_chunk = prefill_chunk
         self.slots = [Slot(i) for i in range(n_slots)]
         self.effective_slots = n_slots
         self.last_step_tokens = 0  # generated tokens appended by the last commit
+        # this step's prompt chunk, fixed by plan_feed and applied by commit
+        self.chunk: Chunk | None = None
         # optional repro.obs EventLog (the server wires its own): admission,
         # prefill->decode transitions, and completions become request.* events
         # that repro.obs.trace correlates into per-rid lifecycle spans
@@ -80,12 +105,20 @@ class ContinuousBatchingScheduler:
         return sum(not s.free for s in self.slots)
 
     def attended_positions(self) -> int:
-        """KV positions the next decode step attends, summed over active
-        slots: the fed token's position plus one."""
-        return sum(
-            s.pos + 1 if s.phase == PREFILL else s.request.prompt_len + len(s.generated)
-            for s in self.slots if not s.free
-        )
+        """KV positions the planned step attends, summed over its tokens: a
+        fed token's position plus one, for each row and each chunk token."""
+        total = 0
+        for s in self.slots:
+            if s.free:
+                continue
+            if self._in_chunk(s):
+                n = self.chunk.n
+                total += n * s.pos + n * (n + 1) // 2
+            elif s.phase == PREFILL:
+                total += s.pos + 1
+            else:
+                total += s.request.prompt_len + len(s.generated)
+        return total
 
     def admit(self, queue: RequestQueue, step: int) -> tuple[list[Slot], list[CompletedRequest]]:
         """Fill free slots from the queue up to the effective capacity.
@@ -98,7 +131,7 @@ class ContinuousBatchingScheduler:
             if not slot.free:
                 continue
             req = queue.pop_ready(step)
-            while req is not None and req.min_steps_to_finish() + 1 > self.smax:
+            while req is not None and req.positions_needed() > self.smax:
                 # cannot fit in the KV cache; reject rather than overflow
                 rejected.append(self._rejected(req, step))
                 req = queue.pop_ready(step)
@@ -129,16 +162,46 @@ class ContinuousBatchingScheduler:
     # one batched step
     # ------------------------------------------------------------------ #
     def plan_feed(self) -> np.ndarray:
-        """(n_slots, 1) int32 token to feed each slot this step."""
+        """(n_slots, 1) int32 token to feed each slot this step.
+
+        Also fixes the step's prompt chunk (``self.chunk``: None without a
+        prefilling slot or where the model takes none), which :meth:`commit`
+        applies; the chunk's slot feeds through its chunk, not its row."""
         feed = np.zeros((self.n_slots, 1), np.int32)
+        self.chunk = None
+        prefilling = [s for s in self.slots if not s.free and s.phase == PREFILL]
+        if self.prefill_chunk and prefilling:
+            s = min(prefilling, key=lambda s: (s.admitted_step, s.index))
+            n = min(self.prefill_chunk, s.request.prompt_len - s.pos)
+            tokens = np.zeros(self.prefill_chunk, np.int32)
+            tokens[:n] = s.request.prompt[s.pos:s.pos + n]
+            self.chunk = Chunk(s.index, tokens, n)
         for s in self.slots:
-            if s.free:
+            if s.free or self._in_chunk(s):
                 continue
             if s.phase == PREFILL:
                 feed[s.index, 0] = s.request.prompt[s.pos]
             else:
                 feed[s.index, 0] = s.generated[-1]
         return feed
+
+    def _in_chunk(self, s: Slot) -> bool:
+        return self.chunk is not None and s.index == self.chunk.slot
+
+    def chunk_feed(self) -> dict:
+        """The planned step's chunk inputs of ``repro.models.lm.decode_step``
+        (host arrays): ``advance``, the rows whose token is fed, and
+        ``chunk`` {tokens, slot, len}.  Only for a step with a chunk: a
+        step without one feeds the bare token array, every row advancing."""
+        c = self.chunk
+        advance = np.array([not (s.free or self._in_chunk(s)) for s in self.slots])
+        return {"advance": advance,
+                "chunk": {"tokens": c.tokens, "slot": np.int32(c.slot), "len": np.int32(c.n)}}
+
+    @property
+    def prefill_tokens(self) -> int:
+        """Prompt tokens the planned step's chunk feeds."""
+        return 0 if self.chunk is None else self.chunk.n
 
     def commit(self, sampled: np.ndarray, step: int) -> list[CompletedRequest]:
         """Advance every active slot given this step's sampled tokens.
@@ -151,7 +214,7 @@ class ContinuousBatchingScheduler:
                 continue
             req = s.request
             if s.phase == PREFILL:
-                s.pos += 1
+                s.pos += self.chunk.n if self._in_chunk(s) else 1
                 if s.pos < req.prompt_len:
                     if req.deadline_step is not None and step >= req.deadline_step:
                         done.append(self._finish(s, step, "expired"))

@@ -13,16 +13,22 @@ The step loop wires the scheduler and fault manager around one jitted decode:
                                  number of decode slots admission may fill;
       4. admission             — freed slots take queued requests (their KV
                                  cache slots are zeroed in place);
-      5. batched decode        — ONE decode_step over all slots; every weight
+      5. batched decode        — ONE decode_step over all slots, plus the
+                                 step's prompt chunk of up to
+                                 ``prefill_chunk`` tokens of one prefilling
+                                 slot (attention families; a step with no
+                                 prefilling slot runs the token-only
+                                 program, no chunk rows); every weight
                                  matmul of the protected layer fraction
                                  (attention projections, FFN, experts, LM
                                  head) runs through the FTContext dispatcher
-                                 on the HyCA virtual array, corrupted by
-                                 whatever faults the runtime has not yet
-                                 confirmed;
-      6. commit                — prefill slots advance a prompt token, decode
-                                 slots append the sampled token, finished
-                                 requests free their slots.
+                                 on the HyCA virtual array, once for the
+                                 slots' rows and the chunk's together,
+                                 corrupted by whatever faults the runtime
+                                 has not yet confirmed;
+      6. commit                — prefill slots advance the prompt tokens
+                                 they fed, decode slots append the sampled
+                                 token, finished requests free their slots.
 
 Mode is a *data* difference, not a code difference — all three modes run the
 identical compiled step, fed different fault views:
@@ -58,7 +64,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.core.engine import FaultState, HyCAConfig, empty_fault_state, identity_plan
 from repro.core.ftcontext import ProtectPolicy, build_ftcontext
 from repro.core.redundancy import DPPUConfig
-from repro.models.lm import LMConfig, decode_step, init_cache, init_params
+from repro.models.lm import CHUNK_FAMILIES, LMConfig, decode_step, init_cache, init_params
 from repro.obs.events import EventLog
 from repro.obs.host import install_gc_hook, span
 from repro.repair.plan import remap_plan
@@ -77,6 +83,11 @@ class ServerConfig:
     smoke: bool = True
     n_slots: int = 4
     smax: int = 96                 # KV capacity per slot
+    # prompt tokens of one prefilling slot that ride in each decode step
+    # (attention families; capped at smax).  The chunk's rows share every
+    # weight matmul with the slots' rows, so a decode step bound by weight
+    # reads takes them at little cost.
+    prefill_chunk: int = 256
     mode: str = "protected"        # off | protected | unprotected
     rows: int = 8                  # virtual PE array (serving-scale)
     cols: int = 8
@@ -127,6 +138,13 @@ class ServerConfig:
         )
 
 
+def prompt_chunk(cfg: ServerConfig, lm: LMConfig) -> int:
+    """The step's prompt chunk size: ``prefill_chunk`` capped at ``smax``,
+    or 0 where the family takes no chunk (a recurrent state has no
+    positions to write a chunk into)."""
+    return min(cfg.prefill_chunk, cfg.smax) if lm.family in CHUNK_FAMILIES else 0
+
+
 # --------------------------------------------------------------------------- #
 # compiled pieces (shareable across fleet replicas)
 # --------------------------------------------------------------------------- #
@@ -135,8 +153,11 @@ class ModelBundle:
     Fleet replicas share a bundle so XLA compiles the step exactly once."""
 
     def __init__(self, cfg: ServerConfig, lm: LMConfig | None = None):
+        if cfg.prefill_chunk < 1:
+            raise ValueError(f"prefill_chunk must be >= 1, got {cfg.prefill_chunk}")
         self.cfg = cfg
         self.lm = lm or (get_smoke_config if cfg.smoke else get_config)(cfg.arch)
+        self.prefill_chunk = prompt_chunk(cfg, self.lm)
         self.hyca = cfg.hyca()
         self.params = init_params(jax.random.key(cfg.seed), self.lm)
         self.max_faults = cfg.rows * cfg.cols
@@ -156,33 +177,42 @@ class ModelBundle:
             plan=self.identity_plan,
         )
 
+        # feed: decode_step's batch — a bare (n_slots, 1) token array (one
+        # token a slot, every row fed), or {"token", "advance", "chunk"}
+        # (ContinuousBatchingScheduler.chunk_feed) for a step with a chunk
+        def _batch(feed):
+            return feed if isinstance(feed, dict) else {"token": feed}
+
+        # one context per feed: with counters, each carries the static call
+        # ledger of its feed's shapes, discovered by abstractly tracing the
+        # decode step once (shapes only) and folded by accumulate() under jit
+        # (repro.obs.counters)
+        ftcs = [self.ftc] * len(self.feed_shapes())
         if cfg.counters:
-            # discover the static call ledger by abstractly tracing the
-            # decode step once (shapes only); attached as FTContext aux so
-            # accumulate() folds it under jit (repro.obs.counters)
             from repro.obs.counters import trace_site_calls
 
             lmc0 = self.lm
             cache_shapes = jax.eval_shape(self.fresh_cache)
-            tok_shape = jax.ShapeDtypeStruct((cfg.n_slots, 1), jnp.int32)
-            ledger = trace_site_calls(
-                lambda c, p, ch, t: decode_step(p, lmc0, ch, {"token": t}, ftc=c),
-                self.ftc, self.params, cache_shapes, tok_shape,
-            )
-            self.ftc = self.ftc.with_ledger(ledger)
+            ftcs = [self.ftc.with_ledger(trace_site_calls(
+                lambda c, p, ch, f: decode_step(p, lmc0, ch, _batch(f), ftc=c),
+                self.ftc, self.params, cache_shapes, feed,
+            )) for feed in self.feed_shapes()]
+        self.ftc = ftcs[0]
+        lmc, tok_ftc, chunk_ftc = self.lm, ftcs[0], ftcs[-1]
 
-        lmc, ftc = self.lm, self.ftc
+        def _ctx(feed):
+            return chunk_ftc if isinstance(feed, dict) and "chunk" in feed else tok_ftc
 
         if cfg.counters:
-            def _step(params, cache, tok, fstate, plan, counters):
-                c = ftc.with_state(fstate).with_plan(plan).with_counters(counters)
-                logits, cache = decode_step(params, lmc, cache, {"token": tok}, ftc=c)
+            def _step(params, cache, feed, fstate, plan, counters):
+                c = _ctx(feed).with_state(fstate).with_plan(plan).with_counters(counters)
+                logits, cache = decode_step(params, lmc, cache, _batch(feed), ftc=c)
                 return logits, cache, c.accumulate()
         else:
-            def _step(params, cache, tok, fstate, plan):
+            def _step(params, cache, feed, fstate, plan):
                 return decode_step(
-                    params, lmc, cache, {"token": tok},
-                    ftc=ftc.with_state(fstate).with_plan(plan),
+                    params, lmc, cache, _batch(feed),
+                    ftc=_ctx(feed).with_state(fstate).with_plan(plan),
                 )
 
         def _reset(cache, slot):
@@ -193,7 +223,9 @@ class ModelBundle:
                 return leaf.at[:, slot].set(jnp.zeros_like(leaf[:, 0]))
             return jax.tree_util.tree_map_with_path(f, cache)
 
-        self.step_fn = jax.jit(_step, donate_argnums=(1,))
+        self._step = jax.jit(_step, donate_argnums=(1,))
+        self._step_compiled = False
+        self.step_fn = self._step
         self.reset_fn = jax.jit(_reset, donate_argnums=(0,))
 
     @property
@@ -208,6 +240,33 @@ class ModelBundle:
 
     def fresh_cache(self) -> Any:
         return init_cache(self.lm, self.cfg.n_slots, self.cfg.smax)
+
+    def feed_shapes(self) -> tuple:
+        """Shapes of each ``feed`` the step takes (the batch of
+        ``decode_step``): the bare token array, then, with a chunk size,
+        the tokens beside a prompt chunk."""
+        i32 = jnp.int32
+        tok = jax.ShapeDtypeStruct((self.cfg.n_slots, 1), i32)
+        if not self.prefill_chunk:
+            return (tok,)
+        return tok, {"token": tok,
+                     "advance": jax.ShapeDtypeStruct((self.cfg.n_slots,), jnp.bool_),
+                     "chunk": {"tokens": jax.ShapeDtypeStruct((self.prefill_chunk,), i32),
+                               "slot": jax.ShapeDtypeStruct((), i32),
+                               "len": jax.ShapeDtypeStruct((), i32)}}
+
+    def compile_step(self, params, cache, fstate, plan, counters=None) -> None:
+        """Compile the step for every feed of :meth:`feed_shapes`, once per
+        bundle, so that a step with no prompt chunk to run (the token-only
+        program) compiles no more mid-run than one with a chunk.  Lowering
+        reads only the arguments' shapes: the donated cache stays valid."""
+        if self._step_compiled:
+            return
+        extra = () if counters is None else (counters,)
+        for shapes in self.feed_shapes():
+            feed = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+            self._step.lower(params, cache, feed, fstate, plan, *extra).compile()
+        self._step_compiled = True
 
     def zero_counters(self):
         from repro.obs.counters import Counters
@@ -272,8 +331,9 @@ class FaultTolerantServer:
             "server.start", mode=cfg.mode, rows=cfg.rows, cols=cfg.cols,
             dppu=cfg.dppu_size, dispatch=cfg.dispatch, arch=self.lm.name,
         )
-        self.queue = RequestQueue()
-        self.scheduler = ContinuousBatchingScheduler(cfg.n_slots, cfg.smax)
+        self.queue = RequestQueue(self.bundle.prefill_chunk)
+        self.scheduler = ContinuousBatchingScheduler(
+            cfg.n_slots, cfg.smax, self.bundle.prefill_chunk)
         # request lifecycle events share the server's log: enqueue/admit/
         # first_token/complete correlate by rid into repro.obs.trace spans
         self.queue.log = self.log
@@ -470,20 +530,28 @@ class FaultTolerantServer:
                     for slot in admitted:
                         self.cache = self.bundle.reset_fn(self.cache, jnp.int32(slot.index))
 
-            # 5. one batched decode over all slots
+            # 5. one batched decode over all slots (and the prompt chunk)
             with span("decode.feed"):
+                tok = self.scheduler.plan_feed()
+                n_prefill = self.scheduler.prefill_tokens
                 positions = self.scheduler.attended_positions() if root.is_enabled() else None
-                feed = self.scheduler.plan_feed()
-                tok = jnp.asarray(feed)
+                if self.scheduler.chunk is None:
+                    # no prompt chunk to run: the token-only step, which
+                    # costs what a step without chunks did
+                    feed = jnp.asarray(tok)
+                else:
+                    feed = jax.device_put({"token": tok, **self.scheduler.chunk_feed()})
                 fstate = self._current_fstate()
             with span("decode.dispatch"):
+                self.bundle.compile_step(self.params, self.cache, fstate, self.plan,
+                                         self.counters)
                 if self.counters is not None:
                     logits, self.cache, self.counters = self.bundle.step_fn(
-                        self.params, self.cache, tok, fstate, self.plan, self.counters,
+                        self.params, self.cache, feed, fstate, self.plan, self.counters,
                     )
                 else:
                     logits, self.cache = self.bundle.step_fn(
-                        self.params, self.cache, tok, fstate, self.plan,
+                        self.params, self.cache, feed, fstate, self.plan,
                     )
             with span("decode.sample"):
                 best = jnp.argmax(logits[:, -1, :], axis=-1)
@@ -515,6 +583,7 @@ class FaultTolerantServer:
                     completed=len(completed),
                     remapped=self.manager.n_remapped,
                     quality_fraction=self.manager.quality_fraction,
+                    prefill_tokens=n_prefill,
                 ), completed)
                 if scan_ok is not None:
                     self._n_scan_steps += 1
@@ -540,7 +609,8 @@ class FaultTolerantServer:
                 self.step_idx += 1
             if positions is not None:
                 root.set_metadata(active=n_active, positions=positions,
-                                  tokens=int(n_decode_tokens), queue=self.queue.depth())
+                                  tokens=int(n_decode_tokens), prefill_tokens=n_prefill,
+                                  queue=self.queue.depth())
                 if load is not None:
                     root.set_metadata(moe_pairs=int(load.sum()), moe_max_load=int(load.max()))
         return completed

@@ -19,8 +19,12 @@ against ``run_fleet`` on identical FleetConfig + TrafficSpec):
   * **request flow** — the queue is an (age × class) count matrix, decode
     slots are per-class countdown histograms (a request of class k occupies
     a slot for ``prompt+gen-1`` steps and emits a token on the last ``gen``
-    of them — exactly the scheduler's token-level chunked prefill
-    accounting, eos-free).  Arrivals come from the shared
+    of them — exactly the scheduler's accounting when every prefilling slot
+    feeds one prompt token a step, eos-free).  The proxy still models that
+    token-level feed: the server matches it with ``prefill_chunk=1`` or a
+    model without a chunk, not the default chunk of 256 prompt tokens a
+    step, which shortens prefill; ``run_vfleet`` refuses a server that
+    would feed a longer chunk.  Arrivals come from the shared
     :func:`~repro.serving.traffic.sample_trace`; least-loaded routing with
     lowest-index tie-break is an exact water-fill (binary-searched level +
     lowest-index extras).  SLA expiry reproduces ``pop_ready`` exactly for
@@ -52,12 +56,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.configs import get_config, get_smoke_config
 from repro.core.campaign import chaos_maps, chaos_signatures
 from repro.core.engine import empty_fault_state
 from repro.core.scan import probe_operands
 from repro.obs.series import SeriesBuffer
 from repro.runtime.elastic import initial_spares
 from repro.serving.fleet import FleetConfig
+from repro.serving.server import prompt_chunk
 from repro.serving.traffic import sample_trace
 
 _INF = np.int32(1 << 30)
@@ -417,6 +423,12 @@ def _build(cfg: FleetConfig):
         raise ValueError("run_vfleet does not model repro.repair remediation")
     if s.rows % s.scan_block:
         raise ValueError("scan_block must divide rows")
+    lm = (get_smoke_config if s.smoke else get_config)(s.arch)
+    if prompt_chunk(s, lm) > 1:
+        raise ValueError(
+            "run_vfleet models one prompt token a step per prefilling slot, "
+            f"but {s.arch} serves a prompt chunk of up to {prompt_chunk(s, lm)} "
+            "tokens a step: set ServerConfig.prefill_chunk=1 to compare the engines")
 
     auto = cfg.autoscale
     R = max(cfg.n_replicas, auto.max_replicas) if auto is not None else cfg.n_replicas

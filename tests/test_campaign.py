@@ -327,7 +327,9 @@ def test_server_on_step_hook_runs_chaos():
         if s.step_idx == 2 and "n" not in seen:
             seen["n"] = cp.apply_chaos(s.injector, cmap)
 
-    srv.run([{"step": 0, "prompt": [1, 2], "max_new_tokens": 2}],
+    # the prompt rides in step 0's chunk: four new tokens keep the request
+    # in flight through step 3, past the hook's step 2
+    srv.run([{"step": 0, "prompt": [1, 2], "max_new_tokens": 4}],
             max_steps=6, on_step=hook)
     assert seen["n"] == int(cmap.sum())
     assert srv.injector.n_faults == int(cmap.sum())

@@ -15,6 +15,9 @@ program holds the Pallas kernel (``tpu_custom_call``):
   * ``ft_matmul_batched`` fed bf16 through the fused ``FTContext`` expert
     dispatch at deepseek-v2-lite's decode share (128 slots, 16 held
     experts, d_model 2048, d_expert 1408);
+  * both dispatches with a 256-token prompt chunk beside the slots' rows
+    (starcoder2-3b: 320 rows; deepseek-v2-lite's experts: 384), each one
+    row block of the heuristic's;
   * ``probe_check`` at the server's probe shapes (8x8 and 32x32 arrays) and
     at K > window.
 
@@ -112,6 +115,22 @@ def test_fused_dispatch_bf16_compiles_at_4_slots(one_chip, k, n):
     )
 
 
+@pytest.mark.parametrize("k,n", STARCODER2_DECODE)
+def test_fused_dispatch_bf16_compiles_with_a_prompt_chunk(one_chip, k, n):
+    """64 slots' rows and a 256-token prompt chunk through ``FTContext``:
+    320 rows, one row block, so the grid reads each weight tile once."""
+    hyca = dla_config()
+    ctx = dataclasses.replace(
+        build_ftcontext(empty_fault_state(hyca.rows * hyca.cols), hyca, dispatch="fused"),
+        fused_backend="pallas",
+    )
+    assert ctx._block_for(320, n, k, jnp.bfloat16) == (320, 128, 128)
+    _assert_kernel(
+        lambda x, w: ctx.matmul(x, w, site="ffn"),
+        _sds((320, 1, k), jnp.bfloat16, one_chip), _sds((k, n), jnp.bfloat16, one_chip),
+    )
+
+
 @pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)])
 def test_ft_matmul_batched_compiles(one_chip, k, n):
     e, m = 64, 8
@@ -134,6 +153,22 @@ def test_fused_expert_dispatch_bf16_compiles_at_the_v2lite_share(one_chip, spec,
     _assert_kernel(
         lambda x, w: ctx.einsum(spec, x, w, site="moe.expert"),
         _sds((128, 16, 1, k), jnp.bfloat16, one_chip), _sds((16, k, n), jnp.bfloat16, one_chip),
+    )
+
+
+@pytest.mark.parametrize("spec,k,n", [("becd,edf->becf", 2048, 1408),
+                                       ("becf,efd->becd", 1408, 2048)])
+def test_fused_expert_dispatch_bf16_compiles_with_a_prompt_chunk(one_chip, spec, k, n):
+    """128 slots and a 256-token prompt chunk as one-token groups over 16
+    held experts, bf16: 384 rows an expert, one row block."""
+    hyca = dla_config()
+    ctx = dataclasses.replace(
+        build_ftcontext(empty_fault_state(hyca.rows * hyca.cols), hyca, dispatch="fused"),
+        fused_backend="pallas",
+    )
+    _assert_kernel(
+        lambda x, w: ctx.einsum(spec, x, w, site="moe.expert"),
+        _sds((384, 16, 1, k), jnp.bfloat16, one_chip), _sds((16, k, n), jnp.bfloat16, one_chip),
     )
 
 

@@ -242,10 +242,16 @@ def test_server_step_records_the_routing_load(arch, counters, monkeypatch):
         srv.step()
     roots = [s for s in _Span.made if s.name == "server.step"]
     assert len(roots) == 4 and all(r.attrs["active"] == 2 for r in roots)
+    # step 0: the 6-token prompt rides as the chunk, the other slot feeds one
+    # prompt token; step 1: the other slot's last 2 as the chunk, beside one
+    # generated token; then one token a slot
+    assert [r.attrs["prefill_tokens"] for r in roots] == [6, 2, 0, 0]
+    fed = [7, 3, 2, 2]
     if arch == ARCH:
         cfg = srv.lm
-        full = 2 * cfg.moe.top_k * (cfg.n_layers - cfg.first_k_dense)
-        assert all(r.attrs["moe_pairs"] == full and 1 <= r.attrs["moe_max_load"] <= 2 for r in roots)
+        pairs = cfg.moe.top_k * (cfg.n_layers - cfg.first_k_dense)
+        assert [r.attrs["moe_pairs"] for r in roots] == [n * pairs for n in fed]
+        assert all(1 <= r.attrs["moe_max_load"] <= n for r, n in zip(roots, fed))
         last = np.asarray(srv.cache["moe_load"])          # every slot's row; two are busy
         assert last.shape == (cfg.n_layers - cfg.first_k_dense, 4, cfg.moe.n_held)
         assert last.sum() >= roots[-1].attrs["moe_pairs"]

@@ -350,7 +350,36 @@ def test_pallas_tile_alignment_rejected():
 def test_default_block_heuristic():
     assert autotune.default_block(4, 512, 64) == (8, 128, 128)    # decode row
     assert autotune.default_block(100, 512, 64) == (104, 128, 128)
+    # a decode step with its prompt chunk: one row block, each weight read once
+    assert autotune.default_block(320, 512, 64, dtype=jnp.bfloat16) == (320, 128, 128)
+    assert autotune.default_block(512, 512, 64) == (512, 128, 128)
+    assert autotune.default_block(513, 512, 64) == (128, 128, 128)
     assert autotune.default_block(4096, 512, 64) == (128, 128, 128)
+
+
+@pytest.mark.parametrize("pe_row", [0, 1])
+def test_one_row_block_places_unrepaired_faults_of_a_200_row_matmul(rng, pe_row):
+    """Up to ``ONE_BLOCK_ROWS`` rows run as one row block, so every row of
+    an unprotected 200-row matmul maps to PE row 0 in the kernel: a stuck-at
+    fault at PE (0, 0) corrupts columns 0-127 of every row, one at PE (1, 0)
+    nothing (128-row blocks would put rows 128-199 on PE row 1)."""
+    x = jnp.asarray(rng.standard_normal((200, 64)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((64, 256)), jnp.float32)
+    fault = FaultState(jnp.asarray([[pe_row, 0]], jnp.int32), jnp.asarray([30], jnp.int32),
+                       jnp.asarray([1], jnp.int32))
+
+    def run(state):
+        ctx = build_ftcontext(state, _hyca("unprotected"), dispatch="fused")
+        ctx = dataclasses.replace(ctx, fused_backend="interpret")
+        return np.asarray(ctx.matmul(x, w, site="ffn"))
+
+    assert autotune.default_block(200, 256, 64) == (200, 128, 128)
+    bad = run(fault) != run(empty_fault_state(1))
+    assert not bad[:, 128:].any()
+    if pe_row == 0:
+        assert bad[:, :128].any(axis=1).all()
+    else:
+        assert not bad.any()
 
 
 def test_resolve_block_cache_roundtrip(tmp_path, monkeypatch):

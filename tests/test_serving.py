@@ -105,8 +105,11 @@ def test_ttft_and_queue_metrics(bundle):
         srv.submit(t["prompt"], t["max_new_tokens"])
     s = srv.run(max_steps=60)
     assert s["requests_completed"] == 6
-    # TTFT of a prefill of 4 is at least 4 steps; queued requests wait longer
-    assert s["ttft_mean_steps"] >= 4
+    # a prompt of 4 fits one chunk, and the slots admitted together take the
+    # chunk in turn, one a step: rids 0-3 (admitted at step 0) get their
+    # first token at steps 0, 1, 2, 3; rids 4 and 5 wait in the queue for
+    # slots freed at steps 2 and 3, and get theirs at steps 4 and 5
+    assert s["ttft_mean_steps"] == (0 + 1 + 2 + 3 + 4 + 5) / 6
     assert s["queue_depth_mean"] > 0
 
 
@@ -274,18 +277,27 @@ def test_queue_drops_unmeetable_deadlines():
 
 
 def test_queue_admits_exactly_feasible_deadline(bundle):
-    # admitted at step s, a request finishes at s + min_steps_to_finish() - 1;
-    # a deadline equal to that must be admitted (and met), not dropped
+    # admitted at step s, a request finishes at s + min_steps_to_finish(C) - 1;
+    # a deadline equal to that must be admitted (and met), not dropped.
+    # One prompt token a step (C = 0): 4 + 4 - 1 = 7 steps
     q = RequestQueue()
     req = Request(rid=0, prompt=np.arange(4), max_new_tokens=4, deadline_step=6)
     assert req.min_steps_to_finish() == 7
     q.submit(req)
     assert q.pop_ready(step=0) is req and not q.drained_expired()
+    # the server's chunk takes the whole prompt in the admission step, whose
+    # logits give the first token: 1 + 4 - 1 = 4 steps
     srv = _server(bundle, "off")
-    srv.submit(np.arange(4), 4, deadline_step=6)
+    c = srv.bundle.prefill_chunk
+    assert c >= 4 and req.min_steps_to_finish(c) == 4
+    q = RequestQueue(c)
+    for rid, deadline in enumerate((2, 3)):
+        q.submit(Request(rid=rid, prompt=np.arange(4), max_new_tokens=4, deadline_step=deadline))
+    assert q.pop_ready(step=0).rid == 1 and [r.rid for r in q.drained_expired()] == [0]
+    srv.submit(np.arange(4), 4, deadline_step=3)
     srv.run(max_steps=20)
     (done,) = srv.metrics.completions
-    assert done.reason == "done" and done.finish_step == 6
+    assert done.reason == "done" and done.finish_step == 3
 
 
 def test_run_accounts_never_admitted_requests(bundle):

@@ -31,9 +31,12 @@ from repro.serving import (
 )
 from repro.serving.vfleet import _TRACES, batched_confirmed_states
 
+# prefill_chunk=1: the chunk feeds one prompt token a step, so every
+# prefilling slot advances one token a step — the token-level feed that the
+# vectorized engine's decode proxy models
 SERVER = ServerConfig(
     n_slots=2, smax=32, mode="protected", scan_block=2,
-    rows=4, cols=4, dppu_size=2,
+    rows=4, cols=4, dppu_size=2, prefill_chunk=1,
 )
 
 # the pinned cross-engine parity configs: fault_rate=0 (wearout RNG is the
