@@ -1,20 +1,21 @@
 """Telemetry tax: traced + series serving vs. bare serving.
 
-PR 10 threads a device-side :class:`~repro.obs.series.SeriesBuffer` through
-both serving engines and derives lifecycle spans from the event log.  The
-design contract is that none of it costs meaningful wall time: series
-writes are ``dynamic_update_slice`` rows inside the already-jitted step
-(no host sync until harvest), and spans are a pure post-hoc fold over
-events the server was already emitting.  This benchmark pins that contract
-with numbers:
+The vectorized fleet engine carries a device-side
+:class:`~repro.obs.series.SeriesBuffer` through its jitted chunk program;
+the host-loop server reads the same series from its StepRecords and derives
+lifecycle spans from the event log.  The design contract is that none of it
+costs meaningful wall time: series writes are ``dynamic_update_slice`` rows
+inside the already-jitted program (no host sync until harvest), and the
+server's series and spans are pure post-hoc folds over records and events
+the server was already keeping.  This benchmark pins that contract with
+numbers:
 
   * ``vfleet`` row — ``run_vfleet`` on the fleet_goodput quick geometry,
     series off vs. on.  Same chunk count, same chaos event; the series adds
     11 ring channels to the carried state.
   * ``server`` row — the host-loop ``FaultTolerantServer`` under chaos,
-    bare vs. fully traced (series ring + request-lifecycle events + span
-    build + histogram render), the ``launch/serve --series --spans-out``
-    path end to end.
+    bare vs. fully traced (series read + span build + histogram render),
+    the ``launch/serve --series-out --spans-out`` path end to end.
 
 Timing is min-of-repeats with bare/traced repeats interleaved (same
 rationale as ft_overhead: the min rejects scheduler noise, interleaving
@@ -72,11 +73,11 @@ def _time_interleaved(fns: dict[str, callable], repeats: int) -> dict[str, float
     return best
 
 
-def _server_once(*, series: bool, traced: bool, steps: int) -> dict:
+def _server_once(*, traced: bool, steps: int) -> dict:
     """One chaos serve; with ``traced`` also exercise the consumers the
-    launch path runs (span build + histogram text render)."""
+    launch path runs (series read, span build, histogram text render)."""
     srv = FaultTolerantServer(dataclasses.replace(
-        _SERVER, arch="qwen1.5-0.5b", series=series, seed=0))
+        _SERVER, arch="qwen1.5-0.5b", seed=0))
     rng = np.random.default_rng(3)
     trace = [{"step": 0, "prompt": rng.integers(0, 512, size=4),
               "max_new_tokens": 8} for _ in range(6)]
@@ -131,17 +132,15 @@ def run(quick: bool = False) -> dict:
     }]
 
     # ---- host-loop server: bare vs fully traced ------------------------- #
-    warm = _server_once(series=True, traced=True, steps=srv_steps)
+    warm = _server_once(traced=True, steps=srv_steps)
     c.check("traced server still confirms the chaos fault",
             warm["detections"] >= 1, f"detections={warm['detections']}")
     c.check("traced server emits request + fault spans",
             warm["_spans"] > 0, f"spans={warm['_spans']}")
-    _server_once(series=False, traced=False, steps=srv_steps)  # warm bare
+    _server_once(traced=False, steps=srv_steps)  # warm bare
     swall = _time_interleaved({
-        "bare": lambda: _server_once(series=False, traced=False,
-                                     steps=srv_steps),
-        "traced": lambda: _server_once(series=True, traced=True,
-                                       steps=srv_steps),
+        "bare": lambda: _server_once(traced=False, steps=srv_steps),
+        "traced": lambda: _server_once(traced=True, steps=srv_steps),
     }, repeats)
     results.append({
         "path": "server", "n_replicas": 1, "steps": srv_steps,

@@ -16,7 +16,14 @@ carries the whole fault-tolerance story:
     gate evaluated both branches);
   * the dispatch decision (plain / two-pass DPPU / fused Pallas kernel) plus
     the fused backend (compiled TPU kernel, interpret mode, or the pure-jnp
-    oracle), chosen **once** at context build — never per call.
+    oracle), chosen **once** at context build — never per call;
+  * the active repro.repair :class:`~repro.core.engine.RepairPlan` (a traced
+    leaf beside the fault table).
+
+The context computes and nothing else: observation stays outside it.  The
+repro.obs counters are folded beside the compiled step from a call ledger
+that ``repro.obs.trace_site_calls`` discovers once, through the transient
+``_obs_record`` hook — never a pytree field.
 
 Models receive an optional ``ftc`` and route every weight matmul through
 ``ftc.matmul(x, w, site="attn.qkv")`` (or ``ftc.einsum`` for batched expert
@@ -116,9 +123,10 @@ def _as_2d(x: jax.Array) -> tuple[jax.Array, tuple[int, ...]]:
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class FTContext:
-    """Fault-aware execution context.  A pytree: ``state`` is the (traced)
-    leaf, everything else is static aux data — jit a function over an
-    ``FTContext`` argument and only fault-table *values* change per call.
+    """Fault-aware execution context.  A pytree: ``state`` and ``plan`` are
+    the (traced) leaves, everything else is static aux data — jit a function
+    over an ``FTContext`` argument and only fault-table and plan *values*
+    change per call.
 
     Build with :func:`build_ftcontext` (which picks the fused backend for the
     current JAX backend and validates the fault table against the array
@@ -138,12 +146,6 @@ class FTContext:
     # keys, like every other treedef change, recompile once when the plan
     # *structure* first appears).
     plan: object = None
-    # repro.obs: optional Counters pytree (traced leaf — counter value swaps
-    # never recompile) + the static call ledger accumulate() folds it over.
-    # The ledger is aux data: tuple of hashable SiteCall records, fixed per
-    # (model, shapes) at bundle build.
-    counters: object = None
-    ledger: tuple | None = None
     # transient trace-time hook used by repro.obs.trace_site_calls to
     # discover the call ledger; never part of the pytree (a callable is not
     # hashable aux data and must not leak into jit keys)
@@ -154,13 +156,12 @@ class FTContext:
     # ------------------------------------------------------------------ #
     def tree_flatten(self):
         aux = (self.hyca, self.policy, self.dispatch, self.fused_backend,
-               self.fused_block, self.ledger)
-        return (self.state, self.plan, self.counters), aux
+               self.fused_block)
+        return (self.state, self.plan), aux
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
-        return cls(leaves[0], *aux[:5], plan=leaves[1], counters=leaves[2],
-                   ledger=aux[5])
+        return cls(leaves[0], *aux, plan=leaves[1])
 
     # ------------------------------------------------------------------ #
     # static predicates
@@ -191,37 +192,6 @@ class FTContext:
         Keeping the plan *structure* stable (always a plan, identity when no
         remediation is active) makes plan swaps leaf-only: zero recompiles."""
         return dataclasses.replace(self, plan=plan)
-
-    def with_counters(self, counters) -> "FTContext":
-        """Same static context, new repro.obs Counters (a traced leaf —
-        per-step counter carries never recompile)."""
-        return dataclasses.replace(self, counters=counters)
-
-    def with_ledger(self, ledger) -> "FTContext":
-        """Attach the static call ledger (repro.obs.trace_site_calls) that
-        ``accumulate`` folds the counters over.  Aux data: setting it (like
-        any static change) retraces once; it never changes per bundle."""
-        return dataclasses.replace(self, ledger=tuple(ledger))
-
-    def accumulate(self):
-        """One step's counter accumulation: fold every ledger entry's
-        element-exact engine stats (current state + plan) into ``counters``
-        and return the new Counters pytree.
-
-        Runs under jit next to the model forward, NOT inside it: the model's
-        layer stacks execute under ``lax.scan`` with this context closed
-        over, so in-graph per-call accumulation would leak inner tracers.
-        Per-call stats depend only on (state, plan, geometry, shape) — all
-        loop-invariant across the layer scan — so folding the static ledger
-        once per step is exact and leaves the decode graph untouched
-        (docs/observability.md)."""
-        if self.counters is None:
-            raise ValueError("accumulate() needs counters; use with_counters(Counters.zero())")
-        if self.ledger is None:
-            raise ValueError("accumulate() needs a call ledger; use with_ledger(trace_site_calls(...))")
-        from repro.obs.counters import ledger_stats  # deferred: obs imports engine
-
-        return ledger_stats(self.ledger, self.counters, self.state, self.plan, self.hyca)
 
     def _plan_for(self, site: str) -> RepairPlan | None:
         if self.plan is None or isinstance(self.plan, RepairPlan):

@@ -111,20 +111,17 @@ def main(argv=None):
     ap.add_argument("--chaos-model", default="random", choices=["random", "clustered"],
                     help="fault distribution of the chaos map")
     ap.add_argument("--counters", action="store_true",
-                    help="carry the repro.obs device-side Counters leaf through "
-                         "the compiled step (exact fault/recompute accounting; "
-                         "bit-exact with counters off)")
+                    help="fold the repro.obs device-side Counters beside each "
+                         "compiled step (exact fault/recompute accounting; "
+                         "the step is the same program with counters off)")
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="write the event log as JSONL to PATH and a "
                          "Prometheus-style rendering of the summary (gauges "
                          "+ latency histograms) to PATH.prom "
                          "(docs/observability.md)")
-    ap.add_argument("--series", action="store_true",
-                    help="carry a repro.obs SeriesBuffer ring through the "
-                         "step loop (per-step device-side telemetry)")
     ap.add_argument("--series-out", default=None, metavar="PATH",
-                    help="harvest the series ring to PATH.npz (implies "
-                         "--series); feed to python -m repro.obs.replay")
+                    help="write the per-step telemetry series to PATH.npz; "
+                         "feed to python -m repro.obs.replay")
     ap.add_argument("--spans-out", default=None, metavar="PATH",
                     help="derive repro.obs.trace lifecycle spans from the "
                          "event log and write them as JSONL to PATH")
@@ -148,7 +145,6 @@ def main(argv=None):
         scan_block=args.scan_block, fault_rate=args.fault_rate, seed=args.seed,
         repair=args.repair, retrain_steps=args.retrain_steps,
         counters=args.counters,
-        series=args.series or args.series_out is not None,
     )
     server = FaultTolerantServer(cfg)
     if args.faults:
@@ -256,7 +252,7 @@ def main(argv=None):
             "arch": lm.name, "mode": args.mode,
             "start_step": server.series_start_step(),
         })
-        print(f"[serve] series: {server.series.written} steps -> {written}")
+        print(f"[serve] series: {len(server.metrics.steps)} steps -> {written}")
     if args.spans_out:
         from repro.obs.trace import build_traces, write_spans
 

@@ -9,11 +9,11 @@ Seven layers (docs/observability.md):
     collector's pauses.
 
   * :mod:`repro.obs.counters` — device-side FT counters: a :class:`Counters`
-    pytree carried as an optional FTContext leaf, accumulated under jit from
-    a statically-discovered call ledger + the engine's own fault grids.
-    Exact element accounting (fault / recomputed / corrupted / pruned MACs,
-    per-site dispatch counts) with zero retrace on fault-table or plan swaps
-    and a decode graph bit-identical to the counters-off program.
+    pytree folded beside the compiled step (one jitted :func:`fold_ledger`
+    per step) from a statically-discovered call ledger + the engine's own
+    fault grids.  Exact element accounting (fault / recomputed / corrupted /
+    pruned MACs, per-site dispatch counts) with zero retrace on fault-table
+    or plan swaps; the decode step is the same program with counters off.
   * :mod:`repro.obs.events` — structured fault-lifecycle tracing: a
     JSONL-serializable :class:`EventLog` wired through the injector, the
     FaultManager, the repair hook, and the fleet sim; detection and repair
@@ -25,8 +25,9 @@ Seven layers (docs/observability.md):
     with deterministic ids; ``python -m repro.obs.trace`` derives/validates.
   * :mod:`repro.obs.series` — device-side time-series telemetry: a
     :class:`SeriesBuffer` ring pytree carried through the jitted vfleet
-    chunk program and the serving step loop (per-tick queue depth, tokens,
-    fault counts, capacity — zero host sync until harvest).
+    chunk program (per-tick queue depth, tokens, fault counts, capacity —
+    zero host sync until harvest); the server reads the same channels from
+    its StepRecords.
   * :mod:`repro.obs.export` / :mod:`repro.obs.schema` — a Prometheus-style
     text exporter (gauges + latency histograms) for ``--metrics-out``, the
     stdlib HTTP ``/metrics`` scrape endpoint (:mod:`repro.obs.httpd`), and
@@ -42,6 +43,7 @@ committed ``experiments/bench/*.json`` baselines become per-metric budgets
 from repro.obs.counters import (  # noqa: F401
     Counters,
     SiteCall,
+    fold_ledger,
     ledger_stats,
     trace_site_calls,
 )

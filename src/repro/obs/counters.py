@@ -1,9 +1,9 @@
 """Device-side FT counters: exact fault/recompute/dispatch accounting.
 
-A :class:`Counters` pytree rides the FTContext as an optional traced leaf
-(``ftc.with_counters``); one jitted ``ftc.accumulate()`` per step folds the
-per-call engine statistics into it.  Counter values, fault tables, and
-repair plans are all leaves of the same compiled program — swapping any of
+A :class:`Counters` pytree is folded beside the compiled step, never
+inside it: after each step the caller runs :func:`fold_ledger` over the
+step's call ledger, fault state and repair plan.  Counter values, fault
+tables and repair plans are all traced leaves of the fold — swapping any of
 them never retraces (asserted in tests/test_obs.py, the same contract
 tests/test_ftcontext.py pins for the fault table).
 
@@ -19,9 +19,9 @@ step (:func:`trace_site_calls` — ``jax.eval_shape``, no FLOPs), with scan
 multiplicities captured by observing ``lax.scan`` lengths during the trace;
 at run time :func:`ledger_stats` computes each unique (site, shape)'s
 element counts from the live state/plan leaves and scales by multiplicity.
-The decode graph is left literally untouched, which makes the
-counters-on == counters-off bit-exactness structural rather than at the
-mercy of XLA fusion choices.
+The decode step is the same program with counters on or off, which makes
+the counters-on == counters-off bit-exactness structural rather than at
+the mercy of XLA fusion choices.
 
 Counters are int32 (JAX x64 is disabled): at smoke scale (~1e5 elements per
 step) they hold ~20k steps before ``total_elems`` wraps; the lifecycle
@@ -73,7 +73,7 @@ class Counters:
     traced; ``to_host`` folds to plain ints (and derived fractions) only at
     read time."""
 
-    steps: jax.Array             # accumulate() invocations
+    steps: jax.Array             # folded steps
     protected_calls: jax.Array   # matmul calls through the engine path
     plain_calls: jax.Array       # matmul calls lowered to plain jnp.matmul
     site_calls: dict             # {site: int32} — per-site dispatch counts
@@ -210,9 +210,9 @@ def _plan_for(plan, site: str):
 def ledger_stats(ledger: tuple, counters: Counters, state, plan, hyca: HyCAConfig) -> Counters:
     """One step's accumulation: fold every ledger entry's element-exact
     engine stats — computed from the live (state, plan) leaves — into
-    ``counters``.  Pure; runs under the caller's jit.  Shapes repeated
-    across layers cost one stats computation (ledger rows are pre-merged),
-    and the grid scatters XLA-CSEs with the decode graph's own."""
+    ``counters``.  Pure; :func:`fold_ledger` is its jitted form.  Shapes
+    repeated across layers cost one stats computation (ledger rows are
+    pre-merged)."""
     site_calls = dict(counters.site_calls)
     protected_calls = counters.protected_calls
     plain_calls = counters.plain_calls
@@ -235,6 +235,11 @@ def ledger_stats(ledger: tuple, counters: Counters, state, plan, hyca: HyCAConfi
         site_calls=site_calls,
         **stats,
     )
+
+
+# the fold a caller runs after each step, outside it: ``ledger`` and ``hyca``
+# are static, the counters, fault state and plan traced leaves
+fold_ledger = jax.jit(ledger_stats, static_argnums=(0, 4))
 
 
 def elems_on_coords(ledger: tuple, coords, rows: int, cols: int) -> int:

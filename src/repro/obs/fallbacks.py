@@ -2,8 +2,9 @@
 
 A fallback decision (``dispatch="fused"`` site lowering through the twopass
 engine instead of the kernel) is *trace-time static* — it depends only on
-shapes, dtypes, and the spec string, never on array values — so it cannot
-live in the device-side :class:`~repro.obs.counters.Counters` pytree.  This
+shapes, dtypes, and the spec string, never on array values — so it has no
+place in the device-side :class:`~repro.obs.counters.Counters`, which fold
+per-step values beside the compiled step.  This
 module records it at trace time instead: a process-wide counter keyed on
 ``(site, reason)`` (the Prometheus ``site_fallback_total{site,reason}``
 series) plus a one-time ``warnings.warn`` per key so a silently-degraded

@@ -6,14 +6,15 @@ row to every channel with ``lax.dynamic_update_slice_in_dim`` — a pure
 functional update, so the buffer can ride any jitted program as a carried
 leaf: the vectorized fleet engine threads one through its ``lax.scan`` chunk
 program (``run_vfleet(FleetConfig(series=True))``, a leading replica axis on
-every channel) and the serving step loop records one scalar row per step
-(``ServerConfig(series=True)``).
+every channel).  The host-loop server needs no ring: it already holds one
+StepRecord per step, and ``FaultTolerantServer.series_host`` reads the same
+channels from them.
 
 Design rules, mirrored from ``repro.obs.counters``:
 
   * **no host sync on the write path** — ``record`` is trace-time jnp ops;
     the only device→host transfer is :meth:`harvest` (the fleet driver calls
-    it once per chunk, the server once at run end);
+    it once per chunk);
   * **leaf-only** — the buffer's capacity and channel set are fixed at
     creation, so swapping fault tables, chaos maps, or the buffer itself
     never retraces the compiled program (asserted à la test_ftcontext);
@@ -113,19 +114,6 @@ class SeriesBuffer:
             )
         idx = np.arange(start, end) % self.capacity
         return {k: np.asarray(v)[idx] for k, v in sorted(self.data.items())}
-
-
-# jitted append for host-driven loops (the serving step): one dispatch per
-# step, the old buffer donated so the ring is updated in place
-_record = jax.jit(lambda buf, values: buf.record(values), donate_argnums=(0,))
-
-
-def record_step(buf: SeriesBuffer, values: dict) -> SeriesBuffer:
-    """Host-loop entry point: append one row under jit (buffer donated).
-    Values may be plain Python/numpy scalars — they are weakly typed into
-    each channel's dtype on device, so there is no host→device chatter
-    beyond the tiny row itself and no device→host sync at all."""
-    return _record(buf, {k: jnp.asarray(v) for k, v in values.items()})
 
 
 # --------------------------------------------------------------------------- #

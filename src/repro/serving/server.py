@@ -65,6 +65,7 @@ from repro.core.engine import FaultState, HyCAConfig, empty_fault_state, identit
 from repro.core.ftcontext import ProtectPolicy, build_ftcontext
 from repro.core.redundancy import DPPUConfig
 from repro.models.lm import CHUNK_FAMILIES, LMConfig, decode_step, init_cache, init_params
+from repro.obs.counters import Counters, fold_ledger
 from repro.obs.events import EventLog
 from repro.obs.host import install_gc_hook, span
 from repro.repair.plan import remap_plan
@@ -109,18 +110,15 @@ class ServerConfig:
     repair: str = "none"
     retrain_steps: int = 4         # fine-tune budget when repair == "retrain"
     max_remap_fraction: float = 0.5
-    # repro.obs device-side counters: carry a Counters leaf through the
-    # compiled step (docs/observability.md).  Off by default — the ledger
-    # discovery trace at bundle build is the only cost; the decode graph's
-    # dot ops are identical either way.
+    # repro.obs device-side counters: fold a Counters pytree beside each
+    # compiled step from the call ledger of the feed that ran
+    # (docs/observability.md).  Off by default — the ledger discovery trace
+    # at bundle build and one jitted fold per step are the cost; the decode
+    # step is the same program either way.
     counters: bool = False
-    # repro.obs.series: record one scalar telemetry row per step into a
-    # device-side SeriesBuffer ring (the same channels run_vfleet records
-    # per replica) — harvested with ``series_host()``, persisted by
-    # ``launch/serve --series-out`` (docs/observability.md).  The write is
-    # one donated jitted append per step; no device→host sync until harvest.
-    series: bool = False
-    series_capacity: int = 4096    # ring depth: the last N steps are resident
+    # steps ``series_host()`` returns: the last N StepRecords as the
+    # per-step channels run_vfleet records per replica
+    series_capacity: int = 4096
     # ABFT canary on the scan path (repro.transient.abft, docs/faults.md):
     # each scan step also carries the probe matmul's checksum pair and emits
     # abft.alarm on non-zero syndromes — whole-array, step-granular coverage
@@ -176,44 +174,29 @@ class ModelBundle:
             dispatch=cfg.dispatch,
             plan=self.identity_plan,
         )
+        ftc, lmc = self.ftc, self.lm
 
         # feed: decode_step's batch — a bare (n_slots, 1) token array (one
         # token a slot, every row fed), or {"token", "advance", "chunk"}
         # (ContinuousBatchingScheduler.chunk_feed) for a step with a chunk
-        def _batch(feed):
-            return feed if isinstance(feed, dict) else {"token": feed}
+        def _step(params, cache, feed, fstate, plan):
+            batch = feed if isinstance(feed, dict) else {"token": feed}
+            return decode_step(params, lmc, cache, batch,
+                               ftc=ftc.with_state(fstate).with_plan(plan))
 
-        # one context per feed: with counters, each carries the static call
-        # ledger of its feed's shapes, discovered by abstractly tracing the
-        # decode step once (shapes only) and folded by accumulate() under jit
+        # with counters, the static call ledger of each feed of
+        # feed_shapes(), discovered by abstractly tracing the step once
+        # (shapes only); the server folds it beside each step
         # (repro.obs.counters)
-        ftcs = [self.ftc] * len(self.feed_shapes())
+        self.ledgers: tuple | None = None
         if cfg.counters:
             from repro.obs.counters import trace_site_calls
 
-            lmc0 = self.lm
             cache_shapes = jax.eval_shape(self.fresh_cache)
-            ftcs = [self.ftc.with_ledger(trace_site_calls(
-                lambda c, p, ch, f: decode_step(p, lmc0, ch, _batch(f), ftc=c),
-                self.ftc, self.params, cache_shapes, feed,
-            )) for feed in self.feed_shapes()]
-        self.ftc = ftcs[0]
-        lmc, tok_ftc, chunk_ftc = self.lm, ftcs[0], ftcs[-1]
-
-        def _ctx(feed):
-            return chunk_ftc if isinstance(feed, dict) and "chunk" in feed else tok_ftc
-
-        if cfg.counters:
-            def _step(params, cache, feed, fstate, plan, counters):
-                c = _ctx(feed).with_state(fstate).with_plan(plan).with_counters(counters)
-                logits, cache = decode_step(params, lmc, cache, _batch(feed), ftc=c)
-                return logits, cache, c.accumulate()
-        else:
-            def _step(params, cache, feed, fstate, plan):
-                return decode_step(
-                    params, lmc, cache, _batch(feed),
-                    ftc=_ctx(feed).with_state(fstate).with_plan(plan),
-                )
+            self.ledgers = tuple(trace_site_calls(
+                lambda c, p, ch, f: _step(p, ch, f, c.state, c.plan),
+                ftc, self.params, cache_shapes, feed,
+            ) for feed in self.feed_shapes())
 
         def _reset(cache, slot):
             def f(path, leaf):
@@ -255,28 +238,34 @@ class ModelBundle:
                                "slot": jax.ShapeDtypeStruct((), i32),
                                "len": jax.ShapeDtypeStruct((), i32)}}
 
-    def compile_step(self, params, cache, fstate, plan, counters=None) -> None:
-        """Compile the step for every feed of :meth:`feed_shapes`, once per
-        bundle, so that a step with no prompt chunk to run (the token-only
-        program) compiles no more mid-run than one with a chunk.  Lowering
-        reads only the arguments' shapes: the donated cache stays valid."""
+    def compile_step(self, params, cache, fstate, plan) -> None:
+        """Compile the step for every feed of :meth:`feed_shapes`, and with
+        counters the fold of each feed's ledger, once per bundle, so that a
+        step with no prompt chunk to run (the token-only program) compiles
+        no more mid-run than one with a chunk.  Lowering reads only the
+        arguments' shapes: the donated cache stays valid."""
         if self._step_compiled:
             return
-        extra = () if counters is None else (counters,)
         for shapes in self.feed_shapes():
             feed = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
-            self._step.lower(params, cache, feed, fstate, plan, *extra).compile()
+            self._step.lower(params, cache, feed, fstate, plan).compile()
+        for ledger in self.ledgers or ():
+            fold_ledger.lower(ledger, Counters.zero(), fstate, plan, self.hyca).compile()
         self._step_compiled = True
-
-    def zero_counters(self):
-        from repro.obs.counters import Counters
-
-        return Counters.zero()
 
 
 # --------------------------------------------------------------------------- #
 # the server
 # --------------------------------------------------------------------------- #
+# the integer series channels and the StepRecord field each is read from
+_SERIES_FIELDS = {
+    "active": "active_slots", "confirmed": "confirmed_faults",
+    "effective_slots": "effective_slots", "queue_depth": "queue_depth",
+    "surviving_cols": "surviving_cols", "tokens": "tokens_generated",
+    "true_faults": "true_faults",
+}
+
+
 class FaultTolerantServer:
     def __init__(self, cfg: ServerConfig, *, bundle: ModelBundle | None = None,
                  injector: FaultInjector | None = None):
@@ -300,21 +289,7 @@ class FaultTolerantServer:
         self.log = EventLog()
         # the collector's pauses become hyca.python.gc spans and counters
         install_gc_hook()
-        self.counters = self.bundle.zero_counters() if cfg.counters else None
-        self.series = None
-        self._n_scan_steps = 0
-        if cfg.series:
-            from repro.obs.series import SeriesBuffer
-
-            i32, f32 = jnp.int32, jnp.float32
-            self.series = SeriesBuffer.create(cfg.series_capacity, {
-                "tokens": ((), i32), "queue_depth": ((), i32),
-                "active": ((), i32), "confirmed": ((), i32),
-                "effective_slots": ((), i32), "true_faults": ((), i32),
-                "surviving_cols": ((), i32),
-                "scan_coverage": ((), f32), "capacity_fraction": ((), f32),
-                "quality_fraction": ((), f32),
-            })
+        self.counters = Counters.zero() if cfg.counters else None
         self.injector = injector or FaultInjector(cfg.rows, cfg.cols, seed=cfg.seed + 1)
         self.injector.log = self.log
         self.manager = FaultManager(
@@ -471,18 +446,24 @@ class FaultTolerantServer:
         """Host-folded device counters (None when ``cfg.counters`` is off)."""
         return None if self.counters is None else self.counters.to_host()
 
-    def series_host(self) -> dict | None:
-        """Resident rows of the telemetry ring as host arrays, oldest first
-        (None when ``cfg.series`` is off).  At most the last
-        ``series_capacity`` steps are still in the ring; the companion
-        ``series_start_step()`` gives the fleet step of row 0."""
-        if self.series is None:
-            return None
-        return self.series.harvest(start=self.series_start_step())
+    def series_host(self) -> dict:
+        """The per-step telemetry channels run_vfleet records per replica,
+        read from the last ``series_capacity`` StepRecords as host arrays,
+        oldest first; ``series_start_step()`` gives the step of row 0."""
+        start = self.series_start_step()
+        recs = self.metrics.steps[start:]
+        scans = np.cumsum([r.scan_ok is not None for r in self.metrics.steps])[start:]
+        series = {ch: np.array([getattr(r, f) for r in recs], np.int32)
+                  for ch, f in _SERIES_FIELDS.items()}
+        series["capacity_fraction"] = np.array(
+            [r.surviving_cols / self.cfg.cols for r in recs], np.float32)
+        series["quality_fraction"] = np.array([r.quality_fraction for r in recs], np.float32)
+        series["scan_coverage"] = np.minimum(
+            1.0, scans / max(self.metrics.steps_per_sweep, 1)).astype(np.float32)
+        return dict(sorted(series.items()))
 
     def series_start_step(self) -> int:
-        return 0 if self.series is None else max(
-            0, self.series.written - self.series.capacity)
+        return max(0, len(self.metrics.steps) - self.cfg.series_capacity)
 
     # ------------------------------------------------------------------ #
     def step(self) -> list[CompletedRequest]:
@@ -535,7 +516,8 @@ class FaultTolerantServer:
                 tok = self.scheduler.plan_feed()
                 n_prefill = self.scheduler.prefill_tokens
                 positions = self.scheduler.attended_positions() if root.is_enabled() else None
-                if self.scheduler.chunk is None:
+                chunked = self.scheduler.chunk is not None
+                if not chunked:
                     # no prompt chunk to run: the token-only step, which
                     # costs what a step without chunks did
                     feed = jnp.asarray(tok)
@@ -543,16 +525,10 @@ class FaultTolerantServer:
                     feed = jax.device_put({"token": tok, **self.scheduler.chunk_feed()})
                 fstate = self._current_fstate()
             with span("decode.dispatch"):
-                self.bundle.compile_step(self.params, self.cache, fstate, self.plan,
-                                         self.counters)
-                if self.counters is not None:
-                    logits, self.cache, self.counters = self.bundle.step_fn(
-                        self.params, self.cache, feed, fstate, self.plan, self.counters,
-                    )
-                else:
-                    logits, self.cache = self.bundle.step_fn(
-                        self.params, self.cache, feed, fstate, self.plan,
-                    )
+                self.bundle.compile_step(self.params, self.cache, fstate, self.plan)
+                logits, self.cache = self.bundle.step_fn(
+                    self.params, self.cache, feed, fstate, self.plan,
+                )
             with span("decode.sample"):
                 best = jnp.argmax(logits[:, -1, :], axis=-1)
                 load = self.cache.get("moe_load") if positions is not None else None
@@ -585,27 +561,13 @@ class FaultTolerantServer:
                     quality_fraction=self.manager.quality_fraction,
                     prefill_tokens=n_prefill,
                 ), completed)
-                if scan_ok is not None:
-                    self._n_scan_steps += 1
-                if self.series is not None:
-                    # every value is already host-resident (the StepRecord above
-                    # uses the same ones), so the series path adds zero host sync —
-                    # just one donated jitted ring append
-                    from repro.obs.series import record_step as _series_record
-
-                    self.series = _series_record(self.series, {
-                        "tokens": int(n_decode_tokens),
-                        "queue_depth": self.queue.depth(),
-                        "active": n_active,
-                        "confirmed": self.manager.n_confirmed,
-                        "effective_slots": eff,
-                        "true_faults": self.injector.n_faults,
-                        "surviving_cols": self.manager.surviving_cols,
-                        "scan_coverage": min(
-                            1.0, self._n_scan_steps / max(self.metrics.steps_per_sweep, 1)),
-                        "capacity_fraction": float(self.manager.capacity_fraction),
-                        "quality_fraction": float(self.manager.quality_fraction),
-                    })
+                if self.counters is not None:
+                    # the counters fold beside the step, over the ledger of
+                    # the feed that ran and the step's fault state and plan
+                    self.counters = fold_ledger(
+                        self.bundle.ledgers[1 if chunked else 0], self.counters,
+                        fstate, self.plan, self.bundle.hyca,
+                    )
                 self.step_idx += 1
             if positions is not None:
                 root.set_metadata(active=n_active, positions=positions,

@@ -1,10 +1,11 @@
 """repro.obs acceptance tests — counters, event tracing, export, regression gate.
 
-  * Counters are carried as an FTContext leaf and accumulated under jit:
-    counters-on is BIT-EXACT with counters-off across all ten registry
-    configs in both dispatch modes, with zero recompilations across
+  * Counters are folded beside the jitted step: decode-then-fold is
+    BIT-EXACT with decode alone across all ten registry configs in both
+    dispatch modes, with zero recompilations of either program across
     fault-table / plan / counter swaps (the same contract
-    tests/test_ftcontext.py pins for the fault table);
+    tests/test_ftcontext.py pins for the fault table); the server's step
+    lowers to the same program with counters on or off;
   * protected_view_stats matches a per-element numpy brute force of the
     engine's out[i, j] -> PE(i % rows, col_map[j % cols]) mapping;
   * ledger discovery sees through lax.scan: per-site counts carry the layer
@@ -38,7 +39,13 @@ from repro.core.engine import (
 )
 from repro.core.ftcontext import SITES, build_ftcontext
 from repro.models.lm import forward, init_params
-from repro.obs.counters import Counters, elems_on_coords, trace_site_calls
+from repro.obs.counters import (
+    Counters,
+    elems_on_coords,
+    fold_ledger,
+    ledger_stats,
+    trace_site_calls,
+)
 from repro.obs.events import (
     EventLog,
     detection_records,
@@ -177,9 +184,10 @@ def test_elems_on_coords_counts_protected_volume(rng):
 # the headline contract: counters-on == counters-off, zero retraces
 # --------------------------------------------------------------------------- #
 def _counted_pair(cfg, dispatch, rng):
-    """(jitted counters-on fn, jitted counters-off fn, args, ftc) for one
-    arch: the on-variant threads a Counters leaf and accumulates from the
-    ledger; the decode graph itself is identical."""
+    """(jitted decode, counter fold, args, decode trace list) for one arch:
+    "counters on" runs the decode, then folds the counters from the
+    ledger beside it (repro.obs.counters.fold_ledger); "counters off" runs
+    the decode alone."""
     params = init_params(jax.random.key(0), cfg)
     batch = _batch_for(cfg, 1, _seq_for(cfg), rng)
     state = _state(3, seed=5, visible=True, pad_to=8)
@@ -188,45 +196,45 @@ def _counted_pair(cfg, dispatch, rng):
     ledger = trace_site_calls(
         lambda c, p, b: forward(p, cfg, b, ftc=c), ftc, params, batch
     )
-    ftc = ftc.with_ledger(ledger)
     traces = []
 
     @jax.jit
-    def f_on(fstate, plan, counters, p, b):
+    def decode(fstate, plan, p, b):
         traces.append(1)
-        c = ftc.with_state(fstate).with_plan(plan).with_counters(counters)
-        logits, _ = forward(p, cfg, b, ftc=c)
-        return logits, c.accumulate()
-
-    @jax.jit
-    def f_off(fstate, plan, p, b):
         logits, _ = forward(p, cfg, b, ftc=ftc.with_state(fstate).with_plan(plan))
         return logits
 
-    return f_on, f_off, (params, batch, state, ftc), traces
+    def fold(counters, fstate, plan):
+        return fold_ledger(ledger, counters, fstate, plan, ftc.hyca)
+
+    return decode, fold, (params, batch, state), traces
 
 
 def _assert_counters_bitexact(arch, dispatch, rng):
     cfg = _f32(get_smoke_config(arch))
-    f_on, f_off, (params, batch, state, ftc), traces = _counted_pair(cfg, dispatch, rng)
+    decode, fold, (params, batch, state), traces = _counted_pair(cfg, dispatch, rng)
     plan = identity_plan(ROWS, COLS)
     counters = Counters.zero()
 
-    on1, counters = f_on(state, plan, counters, params, batch)
-    off1 = f_off(state, plan, params, batch)
+    on1 = decode(state, plan, params, batch)
+    counters = fold(counters, state, plan)
+    folds = fold_ledger._cache_size()
+    off1 = decode(state, plan, params, batch)
     np.testing.assert_array_equal(np.asarray(on1), np.asarray(off1))
 
     # leaf-only swaps: new fault table, new plan, accumulated counters —
-    # all three at once must reuse the compiled program
+    # all three at once must reuse both compiled programs
     state2 = _state(4, seed=9, visible=True, pad_to=8)
     plan2 = RepairPlan(
         jnp.asarray(np.random.default_rng(1).permutation(COLS), jnp.int32),
         jnp.zeros((ROWS, COLS), bool),
     )
-    on2, counters = f_on(state2, plan2, counters, params, batch)
-    off2 = f_off(state2, plan2, params, batch)
+    on2 = decode(state2, plan2, params, batch)
+    counters = fold(counters, state2, plan2)
+    off2 = decode(state2, plan2, params, batch)
     np.testing.assert_array_equal(np.asarray(on2), np.asarray(off2))
-    assert len(traces) == 1, "counter/state/plan swap retraced the step"
+    assert len(traces) == 1, "state/plan swap retraced the step"
+    assert fold_ledger._cache_size() == folds, "counter/state/plan swap retraced the fold"
 
     h = counters.to_host()
     assert h["steps"] == 2
@@ -511,6 +519,56 @@ def test_server_counters_summary_and_events(tmp_path):
     assert validate_jsonl(str(p)) == len(srv.log)
     kinds = {e.kind for e in srv.log.events}
     assert "server.start" in kinds and "scan.sweep" in kinds
+
+
+def _lowered_steps(cfg):
+    b = ModelBundle(cfg)
+    cache = b.fresh_cache()
+    return [b._step.lower(b.params, cache, feed, b.empty_state,
+                          b.identity_plan).as_text()
+            for feed in b.feed_shapes()]
+
+
+def test_server_step_is_one_program_with_counters_on_or_off():
+    """The counters fold beside the step, so turning them on changes
+    nothing the step compiles: the same HLO for the token-only feed and
+    the chunk feed."""
+    cfg = dataclasses.replace(SRV, prefill_chunk=4)
+    on = _lowered_steps(dataclasses.replace(cfg, counters=True))
+    off = _lowered_steps(cfg)
+    assert len(on) == len(off) == 2
+    for a, b in zip(on, off):
+        assert "jit__step" in a
+        assert a == b
+
+
+def test_server_counters_match_a_hand_fold_of_each_steps_ledger():
+    """A chunked run's counters are, step for step, ledger_stats over the
+    ledger of the feed that ran, with that step's fault state and plan."""
+    srv = FaultTolerantServer(dataclasses.replace(SRV, prefill_chunk=2, counters=True))
+    step_fn, seen = srv.bundle.step_fn, []
+
+    def spy(params, cache, feed, fstate, plan):
+        seen.append((isinstance(feed, dict), fstate, plan))
+        return step_fn(params, cache, feed, fstate, plan)
+
+    def chaos(s):
+        if s.step_idx == 2:     # a fault the scan has yet to confirm
+            s.injector.inject_at(1, 1, bit=30, val=1)
+
+    srv.bundle.step_fn = spy
+    summary = srv.run(_srv_trace(), max_steps=32, on_step=chaos)
+    assert {chunked for chunked, _, _ in seen} == {True, False}
+    tok_ledger, chunk_ledger = srv.bundle.ledgers
+    assert tok_ledger != chunk_ledger
+    want = Counters.zero()
+    for chunked, fstate, plan in seen:
+        ledger = chunk_ledger if chunked else tok_ledger
+        want = ledger_stats(ledger, want, fstate, plan, srv.bundle.hyca)
+    want = want.to_host()
+    assert want["steps"] == summary["steps"] == len(seen)
+    assert want["fault_elems"] > 0
+    assert summary["counters"] == want == srv.counters_host()
 
 
 def test_repair_events_view_over_log():

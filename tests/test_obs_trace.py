@@ -8,11 +8,13 @@ The contract (ISSUE 10):
     content-addressed ids, schema validation, and latency attributes that
     agree EXACTLY with ``ServingMetrics.summary()`` — both reuse
     ``detection_records`` / ``repair_records``;
-  * **device-side series**: the :class:`SeriesBuffer` ring rides the jitted
-    programs with zero host sync on the write path — series-on is BIT-EXACT
+  * **series**: the :class:`SeriesBuffer` ring rides the jitted vfleet
+    program with zero host sync on the write path — series-on is BIT-EXACT
     with series-off on every shared report key, retrace-free across
     fault-rate / chaos swaps, and the vfleet per-tick rows match the legacy
-    engine's host-side StepRecords on the pinned parity configs;
+    engine's host-side StepRecords on the pinned parity configs; the
+    server's series is read from its own StepRecords, and harvesting it
+    changes nothing the server reports;
   * **consumers**: the replay CLI joins events + series into an incident
     timeline whose latencies equal the summary's; Prometheus histograms
     carry cumulative buckets; the stdlib /metrics endpoint scrapes live;
@@ -39,7 +41,7 @@ from repro.obs.httpd import MetricsServer
 from repro.obs.replay import build_timeline, render_text
 from repro.obs.replay import main as replay_main
 from repro.obs.schema import validate_jsonl
-from repro.obs.series import SeriesBuffer, load_series, record_step, save_series
+from repro.obs.series import SeriesBuffer, load_series, save_series
 from repro.obs.trace import (
     build_traces,
     fault_traces,
@@ -180,7 +182,7 @@ def test_span_jsonl_roundtrip_and_cli(tmp_path, capsys):
 def test_series_ring_wrap_and_harvest():
     buf = SeriesBuffer.create(4, {"x": ((), np.int32)})
     for i in range(6):
-        buf = record_step(buf, {"x": i})
+        buf = buf.record({"x": i})
     assert buf.written == 6 and buf.capacity == 4
     got = buf.harvest(start=2)
     np.testing.assert_array_equal(got["x"], [2, 3, 4, 5])
@@ -199,7 +201,7 @@ def test_series_channel_mismatch_is_an_error():
 def test_series_save_load_roundtrip(tmp_path):
     buf = SeriesBuffer.create(8, {"x": ((3,), np.float32)})
     for i in range(5):
-        buf = record_step(buf, {"x": np.full(3, i, np.float32)})
+        buf = buf.record({"x": np.full(3, i, np.float32)})
     path = save_series(str(tmp_path / "s"), buf.harvest(),
                        meta={"start_step": 2})
     assert path.endswith(".npz")
@@ -290,7 +292,7 @@ def _trace(n=3):
 
 
 def _run_traced():
-    srv = FaultTolerantServer(dataclasses.replace(SRV, series=True))
+    srv = FaultTolerantServer(SRV)
     summary = srv.run(_trace(), max_steps=40, on_step=_chaos)
     return srv, summary
 
@@ -307,8 +309,7 @@ def test_server_series_matches_step_records():
 
 
 def test_server_series_ring_keeps_tail():
-    srv = FaultTolerantServer(dataclasses.replace(
-        SRV, series=True, series_capacity=8))
+    srv = FaultTolerantServer(dataclasses.replace(SRV, series_capacity=8))
     srv.run(_trace(), max_steps=40, on_step=_chaos)
     n = len(srv.metrics.steps)
     start = srv.series_start_step()
@@ -319,14 +320,21 @@ def test_server_series_ring_keeps_tail():
 
 
 def test_server_series_off_is_bitexact():
-    _, on = _run_traced()
-    srv_off = FaultTolerantServer(SRV)
-    off = srv_off.run(_trace(), max_steps=40, on_step=_chaos)
+    """A run whose series is harvested every step gives the summary of one
+    whose series is never read."""
+    _, off = _run_traced()
+
+    def chaos_and_harvest(s):
+        _chaos(s)
+        s.series_host()
+
+    srv_on = FaultTolerantServer(SRV)
+    on = srv_on.run(_trace(), max_steps=40, on_step=chaos_and_harvest)
     # wall-clock readings differ between any two runs
     skip = {"wall_s", "tokens_per_s", "queue_wait_s_p90", "ttft_s_p90"}
     diffs = {k: (off[k], on[k]) for k in off
              if k not in skip and off[k] != on[k]}
-    assert not diffs, f"series-on server diverged: {diffs}"
+    assert not diffs, f"series-harvesting server diverged: {diffs}"
 
 
 def test_replay_timeline_latencies_match_summary_exactly():
